@@ -251,7 +251,8 @@ def bench_kernels() -> List[tuple]:
     )
     np.asarray(g(jv, js))
     t_jax = time_ms(lambda: np.asarray(g(jv, js)))
-    ok = bool((probe(vals[:4096], vset) == np.isin(vals[:4096], vset)).all())
+    ok = bool((probe(vals[:4096], vset, interpret=True)
+               == np.isin(vals[:4096], vset)).all())
     rows.append(("kernels.membership.n1M_m5k", t_np * 1e3,
                  f"numpy={t_np:.1f}ms jit={t_jax:.1f}ms pallas_interpret_ok={ok}"))
     return rows

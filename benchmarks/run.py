@@ -17,8 +17,12 @@ def main() -> None:
                          "emitted numbers are reproducible run-to-run")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import setup_compile_cache
+
     from . import common
     from .common import emit
+
+    setup_compile_cache()
 
     if args.sf is not None:
         common.set_scale(args.sf)

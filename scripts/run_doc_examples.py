@@ -5,7 +5,7 @@ Documentation code must run, not rot: this script extracts each fenced
 ```python block from ``docs/*.md`` and ``README.md``, executes the blocks of
 each file in order in one shared namespace (so a later block may use an
 earlier block's imports), and then runs ``examples/quickstart.py`` end to
-end.  Everything runs at tier-1 scale — a failure means a doc example has
+end in the same process.  Everything runs at tier-1 scale — a failure means a doc example has
 drifted from the real API.
 
   PYTHONPATH=src python scripts/run_doc_examples.py
@@ -16,7 +16,7 @@ from __future__ import annotations
 
 import argparse
 import re
-import subprocess
+import runpy
 import sys
 import time
 from pathlib import Path
@@ -63,18 +63,11 @@ def main(argv=None) -> int:
     print(f"[doc-examples] {total} fenced python blocks executed")
 
     if not args.skip_quickstart:
+        # in this process: the doc blocks already hold the accelerator, and
+        # a child process could not take it over
         t0 = time.perf_counter()
-        env = {"PYTHONPATH": str(ROOT / "src")}
-        import os
-
-        env = {**os.environ, **env}
-        proc = subprocess.run(
-            [sys.executable, str(ROOT / "examples" / "quickstart.py")],
-            env=env, cwd=ROOT, capture_output=True, text=True)
-        if proc.returncode != 0:
-            sys.stderr.write(proc.stdout[-2000:] + proc.stderr[-4000:])
-            print("[doc-examples] quickstart.py FAILED", file=sys.stderr)
-            return 1
+        runpy.run_path(str(ROOT / "examples" / "quickstart.py"),
+                       run_name="__main__")
         print(f"[doc-examples] examples/quickstart.py ok "
               f"({time.perf_counter() - t0:.1f}s)")
     return 0

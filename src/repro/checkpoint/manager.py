@@ -33,8 +33,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from .. import compat  # noqa: F401  (installs jax.sharding.AxisType / make_mesh shims)
-
 
 def _hash(arr: np.ndarray) -> str:
     return hashlib.sha256(np.ascontiguousarray(arr).tobytes()).hexdigest()[:16]

@@ -15,7 +15,10 @@ Each is measured lazily, once per process, on tiny synthetic workloads
 (<100 ms total), cached under a lock, and overridable via environment for CI
 and tests (``PREDTRACE_DEVICE_CUTOVER``, ``PREDTRACE_PARALLEL_CUTOVER``,
 ``PREDTRACE_INSITU_CUTOVER``, ``PREDTRACE_MEMBER_CUTOVER``,
-``PREDTRACE_RLE_CUTOVER`` — integer row thresholds).
+``PREDTRACE_RLE_CUTOVER`` — integer row thresholds).  The device probes
+time the backend's real launch; a launch that raises propagates out of the
+probe, so a broken device path fails instead of reading as "the host
+always wins".
 
 Probes are *invalidatable*: each cached measurement is a :class:`Probe`
 stamped with its wall-clock time and a confidence that decays every time the
@@ -164,10 +167,7 @@ def device_scan_probe(key: str, launch: Callable[[np.ndarray, np.ndarray], np.nd
         def dev(n: int) -> np.ndarray:
             return launch(slabs[n], thr)
 
-        try:
-            rows = measured_crossover(host, dev, sizes)
-        except Exception:
-            rows = float("inf")
+        rows = measured_crossover(host, dev, sizes)
         cut = NEVER if rows == float("inf") else int(
             min(max(rows * n_atoms * batch * 1.25, 1 << 12), NEVER)
         )
@@ -270,10 +270,7 @@ def insitu_scan_probe() -> Probe:
             raw = data[n][0]
             return raw == 550
 
-        try:
-            rows = measured_crossover(decoded, insitu, sizes)
-        except Exception:
-            rows = float("inf")
+        rows = measured_crossover(decoded, insitu, sizes)
         # below the crossover the decoded path wins; clamp to a sane band
         # (inf = the in-situ slope never wins -> always prefer decode)
         if rows == float("inf"):
@@ -319,7 +316,6 @@ def disk_scan_probe() -> Probe:
         rng = np.random.default_rng(23)
         sizes = (1 << 12, 1 << 18)
         tmpdir = tempfile.mkdtemp(prefix="predtrace-probe-")
-        rows = float("inf")
         try:
             paths = {}
             for n in sizes:
@@ -334,10 +330,7 @@ def disk_scan_probe() -> Probe:
             def mapped(n: int) -> np.ndarray:
                 return np.asarray(mmaps[n] > 500)
 
-            try:
-                rows = measured_crossover(loaded, mapped, sizes)
-            except Exception:
-                rows = float("inf")
+            rows = measured_crossover(loaded, mapped, sizes)
             del mmaps
         finally:
             shutil.rmtree(tmpdir, ignore_errors=True)
@@ -387,10 +380,7 @@ def member_scan_probe(key: str,
         def dev(n: int) -> np.ndarray:
             return launch(vals[n], vset)
 
-        try:
-            rows = measured_crossover(host, dev, sizes)
-        except Exception:
-            rows = float("inf")
+        rows = measured_crossover(host, dev, sizes)
         cut = NEVER if rows == float("inf") else int(
             min(max(rows * 1.25, 1 << 12), NEVER)
         )
@@ -443,10 +433,7 @@ def rle_scan_probe(key: str,
             rv, rl = data[n]
             return launch(rv, rl, 0)
 
-        try:
-            rows = measured_crossover(host, dev, sizes)
-        except Exception:
-            rows = float("inf")
+        rows = measured_crossover(host, dev, sizes)
         cut = NEVER if rows == float("inf") else int(
             min(max(rows * 1.25, 1 << 12), NEVER)
         )

@@ -13,13 +13,15 @@ partitioning, a worker pool, or a device mesh is configured:
   out across a thread pool (NumPy releases the GIL in the comparison
   kernels); per-partition masks are merged deterministically by partition
   index, so worker scheduling never changes an answer.
-* **Device meshes** (``distrib/sharding.py``): with a mesh, tables are
-  device_put row-sharded across the (pod, data) axes and scanned by the
-  engine's structure-cached ``jit_scan`` — the Pallas ``pred_filter`` /
-  ``membership`` kernels are the TPU codegen for the same predicates.
-  Zone-map pruning still short-circuits all-pruned scans before any device
-  work.  V-sets are padded to the next power of two with a sentinel so
-  shrinking sets between refinement iterations never retrace.
+* **Device meshes** (``distrib/sharding.py``): with a mesh, the int32-exact
+  columns of each table are device_put row-sharded across the (pod, data)
+  axes and scanned by the engine's structure-cached ``jit_scan``.  Whether
+  a scan is in that exact fragment is decided from the predicate and its
+  binding before launch (:meth:`_DeviceTable.exact`); the host engine
+  answers the rest, and a device error raises.  Zone-map pruning still
+  short-circuits all-pruned scans before any device work.  V-sets are
+  padded to the next power of two with a sentinel so shrinking sets
+  between refinement iterations never retrace.
 
 ``distributed_refine`` — Algorithm 3 on sharded data — is now a thin wrapper:
 it routes the shared :func:`repro.core.iterative.refine` fixpoint through a
@@ -38,14 +40,20 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from .expr import Expr
+from .expr import (BinOp, Col, Expr, IsIn, Lit, Param, ParamSet, UnaryOp,
+                   params_of)
 from .iterative import IterativePlan, refine
 from .lineage import LineageAnswer
 from .scan import ScanEngine, default_engine
 from .table import (PartitionedTable, Table, alive_runs, partition_table,
                     table_uid)
 
-SENTINEL = np.int64(-(2**62))
+INT32_MIN, INT32_MAX = -(2**31), 2**31 - 1
+
+# JAX keeps 64-bit types off by default, so device columns are int32.  This
+# value pads V-set bindings and table rows; device columns never hold it
+# (see _DeviceTable), so a padded set matches no real row
+SENTINEL = np.int32(INT32_MIN)
 
 # don't spin up threads for scans smaller than this many surviving rows —
 # the pool dispatch overhead would dominate
@@ -56,8 +64,44 @@ def _pad_rows(n: int, shards: int) -> int:
     return ((n + shards - 1) // shards) * shards
 
 
+def _int_value(v) -> bool:
+    """A scalar that compares with an int32 lane exactly as numpy compares
+    it with the host column."""
+    return (isinstance(v, (bool, int, np.integer, np.bool_))
+            and INT32_MIN <= int(v) <= INT32_MAX)
+
+
+def _int_set(v) -> bool:
+    """A set binding whose membership test on an int32 lane is exact."""
+    if isinstance(v, (list, tuple)):
+        return all(_int_value(x) for x in v)
+    return (isinstance(v, np.ndarray) and v.ndim == 1
+            and (v.dtype.kind in "iub" or v.size == 0))
+
+
+def _device_set(v) -> np.ndarray:
+    """int32 device form of a set binding, padded to the next power of two
+    with SENTINEL so shrinking sets between refinement iterations don't
+    retrace.  Members outside the device columns' range can never match,
+    so dropping them is exact."""
+    a = np.asarray(v)
+    if a.dtype.kind == "u":
+        a = a[a <= INT32_MAX]  # filter before the cast can wrap
+    a = a.astype(np.int64)
+    a = a[(a > INT32_MIN) & (a <= INT32_MAX)]
+    padded = np.full(1 << max(int(len(a)) - 1, 0).bit_length(), SENTINEL,
+                     np.int32)
+    padded[:len(a)] = a
+    return padded
+
+
 class _DeviceTable:
-    """Row-sharded device-resident numeric view of one table's columns."""
+    """Row-sharded device-resident view of one table's int32-exact columns.
+
+    Only integer and bool columns whose values lie in ``(INT32_MIN,
+    INT32_MAX]`` are uploaded: on the device they are int32 and compare
+    exactly, and none of them holds SENTINEL.  Float columns stay on the
+    host, because float64 compared in float32 gives different answers."""
 
     def __init__(self, table: Table, mesh, axes: Tuple[str, ...],
                  engine: ScanEngine):
@@ -79,39 +123,73 @@ class _DeviceTable:
         self.cols: Dict[str, object] = {}
         for c in table.columns:
             arr = np.asarray(table.cols[c])
-            if arr.dtype.kind == "f":
-                arr = arr.astype(np.float64)
-                pad_val = np.nan
-            else:
-                arr = arr.astype(np.int64)
-                pad_val = SENTINEL
-            padded = np.full(self.padded, pad_val, arr.dtype)
+            if arr.dtype.kind not in "iub":
+                continue
+            if n and not (int(arr.min()) > INT32_MIN
+                          and int(arr.max()) <= INT32_MAX):
+                continue
+            padded = np.full(self.padded, SENTINEL, np.int32)
             padded[:n] = arr
             self.cols[c] = jax.device_put(padded, sh)
 
+    def exact(self, pred: Expr, binding: Dict[str, object]) -> bool:
+        """Does the jitted scan of ``pred`` give numpy's answer bit for bit?
+        True for and/or/not over comparisons and ``IN`` tests between
+        device columns, in-range int scalars and int sets — the fragment
+        lineage predicates live in.  Arithmetic (int32 overflow), floats,
+        UDFs and conditionals stay on the host."""
+
+        def scalar(x: Expr) -> bool:
+            if isinstance(x, Col):
+                return x.name in self.cols
+            if isinstance(x, Lit):
+                return _int_value(x.value)
+            if isinstance(x, Param):
+                return _int_value(binding.get(x.name))
+            if isinstance(x, UnaryOp) and x.op == "year":
+                return scalar(x.operand)
+            return False
+
+        def is_set(x) -> bool:
+            if isinstance(x, (Param, ParamSet)):
+                return _int_set(binding.get(x.name))
+            return isinstance(x, tuple) and _int_set(x)
+
+        def boolean(x: Expr) -> bool:
+            if isinstance(x, BinOp):
+                if x.op in ("and", "or"):
+                    return boolean(x.left) and boolean(x.right)
+                if x.op == "==" and (is_set(x.right) or is_set(x.left)):
+                    other = x.left if is_set(x.right) else x.right
+                    return scalar(other)
+                return (x.op in ("==", "!=", "<", "<=", ">", ">=")
+                        and scalar(x.left) and scalar(x.right))
+            if isinstance(x, UnaryOp) and x.op == "not":
+                return boolean(x.operand)
+            if isinstance(x, IsIn):
+                return scalar(x.operand) and is_set(x.values)
+            return isinstance(x, Lit) and isinstance(x.value, (bool, np.bool_))
+
+        return boolean(pred)
+
     def scan(self, pred: Expr, binding: Dict[str, object]) -> np.ndarray:
         """Jit-compiled predicate scan over the sharded columns -> host mask.
-        V-set bindings are padded to the next power of two with a sentinel so
-        shrinking sets between iterations don't retrace the jit."""
+        Callers check :meth:`exact` first."""
         import jax.numpy as jnp
 
         b = {}
-        for k, v in binding.items():
-            if isinstance(v, np.ndarray):
-                cap = 1 << max(int(np.ceil(np.log2(max(len(v), 1)))), 0)
-                if v.dtype.kind == "f":
-                    padded = np.full(cap, np.nan, np.float64)
-                else:
-                    padded = np.full(cap, SENTINEL, np.int64)
-                padded[: len(v)] = v
-                b[k] = jnp.asarray(padded)
-            else:
-                b[k] = v
+        for k in params_of(pred):
+            v = binding[k]
+            b[k] = jnp.asarray(_device_set(v)) if _int_set(v) else int(v)
         mask = self.engine.jit_scan(pred)(self.cols, b)
         m = np.asarray(mask)
         if m.ndim == 0:  # constant predicate (True/False)
             m = np.broadcast_to(m, (self.padded,))
         return m[: self.nrows]
+
+    def devices(self) -> set:
+        """Ids of the devices holding shards of the uploaded columns."""
+        return {d.id for a in self.cols.values() for d in a.sharding.device_set}
 
 
 class PartitionExecutor:
@@ -343,23 +421,29 @@ class PartitionExecutor:
         # device work; partial pruning stays on-device (slicing per shape
         # would retrace the jit)
         plan = self.engine.partition_plan(pred, table, binding)
-        if plan is not None:
-            if not plan[1].any():
-                self.engine.record_prune(0, len(plan[1]))
-                return np.zeros(table.nrows, dtype=bool)
-            # partial pruning stays on-device: the full sharded scan runs
-            self.engine.record_prune(len(plan[1]), 0)
-        try:
-            dt = self._device_table(table)
-            return dt.scan(pred, binding)
-        except Exception:
-            # predicates outside the jit-able fragment (exotic residuals)
-            # fall back to the host engine — answers over speed
+        if plan is not None and not plan[1].any():
+            self.engine.record_prune(0, len(plan[1]))
+            return np.zeros(table.nrows, dtype=bool)
+        dt = self._device_table(table)
+        if not dt.exact(pred, binding):
+            # outside the exact int32 fragment: the host engine answers
             if plan is not None:
                 return self._fanout_scan(pred, table, binding, plan)
             return self.engine.backend.scan(
                 self.engine.compile(pred), table, binding
             )
+        if plan is not None:
+            # partial pruning stays on-device: the full sharded scan runs
+            self.engine.record_prune(len(plan[1]), 0)
+        self.engine.stats.bump(mesh_scans=1)
+        return dt.scan(pred, binding)
+
+    def placement(self) -> Dict[str, set]:
+        """Device ids holding each uploaded table's columns, by table name
+        (or uid) — where the mesh path really put the data."""
+        return {getattr(t, "name", None) or str(tk): dt.devices()
+                for tk, (ref, dt) in list(self._device.items())
+                if (t := ref()) is not None}
 
     def _device_table(self, table: Table) -> _DeviceTable:
         tk = table_uid(table)

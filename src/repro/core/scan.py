@@ -26,8 +26,8 @@ Backends are pluggable:
   ``kernels/pred_filter`` batched scan: int32 comparison atoms directly,
   float32 comparisons via a monotone sign-folded int32 key lane (exact
   NaN/±inf semantics by threshold translation), and ``IN`` atoms in-grid
-  via per-lane binary search over device-resident sorted set segments
-  (interpret mode on CPU; compiled on TPU).
+  over device-resident sorted set segments (compiled Pallas on a TPU, the
+  jitted XLA twin elsewhere, Pallas interpret mode when asked for).
 * :meth:`ScanEngine.jit_scan` — a structure-cached ``jax.jit`` of
   ``eval_jnp`` used by the sharded scanner in ``core/distributed.py``.
 
@@ -585,12 +585,11 @@ def _default_kernel_mode() -> str:
     CPU/GPU hosts; Pallas interpret mode stays a correctness-only tool."""
     global _KERNEL_MODE
     if _KERNEL_MODE is None:
-        try:
-            import jax
+        import jax
 
-            plat = jax.devices()[0].platform
-        except Exception:  # pragma: no cover - no usable jax runtime
-            plat = "cpu"
+        # a runtime that cannot list its devices raises here: it is broken,
+        # not a CPU host
+        plat = jax.devices()[0].platform
         _KERNEL_MODE = "pallas" if plat == "tpu" else "xla"
     return _KERNEL_MODE
 
@@ -843,9 +842,9 @@ class PallasBackend(NumpyBackend):
     fused into the launch).  float32 comparisons join the same launch via
     an order-preserving sign-folded int32 key lane with thresholds
     translated exactly (NaN / ±inf / -0.0 semantics match numpy
-    bit-for-bit), and ``IN`` atoms evaluate *in-grid* by per-lane binary
-    search over sorted set segments cached on device next to the slab —
-    one launch carries the whole atom program.  Atoms outside the fragment
+    bit-for-bit), and ``IN`` atoms evaluate *in-grid* against sorted set
+    segments cached on device next to the slab — one launch carries the
+    whole atom program.  Atoms outside the fragment
     (float64 columns, unbound params, residuals) fall back to the NumPy
     oracle — correctness never depends on the kernel fragment.
 
@@ -867,9 +866,9 @@ class PallasBackend(NumpyBackend):
     SLAB_CACHE = 32
     COL_OK_CACHE = 4096
     SET_CACHE = 64
-    # largest total key count one launch's set slab may carry: past this the
-    # linear host probe beats the deepening binary search anyway, and device
-    # set memory stays bounded
+    # largest total key count one launch's set slab may carry: the kernel's
+    # dense membership costs rows x keys per surviving block, and device set
+    # memory stays bounded
     SET_SLAB_LIMIT = 1 << 16
 
     # the slab caches make concurrent scans racy; the parallel partition
@@ -1848,16 +1847,22 @@ class PallasBackend(NumpyBackend):
             if Kp != K:
                 off = np.vstack([off, np.repeat(off[-1:], Kp - K, axis=0)])
                 ln = np.vstack([ln, np.repeat(ln[-1:], Kp - K, axis=0)])
-            kw = dict(set_cols=set_ops.set_cols,
-                      set_slab=jnp.asarray(set_ops.slab),
-                      set_off=jnp.asarray(off), set_len=jnp.asarray(ln),
-                      iters=set_ops.iters)
+            # pad the slab to a power of two (>= one kernel set tile) so
+            # compiles stay bounded; padding lies past every segment
+            slab = set_ops.slab
+            cap = max(1024, 1 << (slab.size - 1).bit_length())
+            if cap != slab.size:
+                slab = np.pad(slab, (0, cap - slab.size))
+            kw = dict(set_cols=set_ops.set_cols, set_slab=jnp.asarray(slab),
+                      set_off=jnp.asarray(off), set_len=jnp.asarray(ln))
         if self.mode == "pallas":
             out = pred_filter_batch(
                 entry.dev, jnp.asarray(thr_pad), static_atoms,
                 jnp.asarray(lo), jnp.asarray(hi),
                 block_rows=self.block_rows, interpret=self.interpret, **kw)
         else:
+            if set_ops is not None:
+                kw["iters"] = set_ops.iters
             out = pred_filter_batch_xla(entry.dev, jnp.asarray(thr_pad),
                                         static_atoms, **kw)
         mask = np.asarray(out)[:K, :entry.n]
@@ -2026,6 +2031,9 @@ class ScanStats:
     decode_chosen: int = 0
     # disk-tier (memmap-backed) stages answered in situ without promotion
     disk_insitu_chosen: int = 0
+    # scans answered by the sharded jit path over a device mesh
+    # (core/distributed.py)
+    mesh_scans: int = 0
     # scans the worker pool actually fanned out (surviving work cleared the
     # measured cutover); zero means the parallel path ran serial throughout
     fanout_scans: int = 0

@@ -81,7 +81,7 @@ def flash_attention(
     window: Optional[int] = None,
     bq: int = DEFAULT_BQ,
     bk: int = DEFAULT_BK,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     BH, S, D = q.shape
     assert S % bq == 0 and S % bk == 0, f"pad S={S} to block multiples"

@@ -11,7 +11,7 @@ from .flash_attn import flash_attention
 from .ref import attention_ref
 
 
-def mha_flash(q, k, v, window: Optional[int] = None, interpret: bool = True):
+def mha_flash(q, k, v, window: Optional[int] = None, interpret: bool = False):
     """q,k,v: [B, S, H, D] (H already GQA-expanded) -> [B, S, H, D]."""
     B, S, H, D = q.shape
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(B * H, S, D)

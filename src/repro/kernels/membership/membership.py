@@ -37,7 +37,7 @@ def membership(
     values: jax.Array,  # [N] int32
     vset: jax.Array,  # [M] int32, padded with a sentinel absent from values
     block_rows: int = BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     (N,) = values.shape
     (M,) = vset.shape

@@ -13,7 +13,7 @@ VMEM_SET_LIMIT = 1 << 16  # 64K int32 = 256 KiB of VMEM for the set
 
 
 def probe(values: np.ndarray, vset: np.ndarray, use_kernel: bool = True,
-          interpret: bool = True) -> np.ndarray:
+          interpret: bool = False) -> np.ndarray:
     """Boolean membership mask, any sizes (pads to kernel block shapes)."""
     values = np.asarray(values, dtype=np.int32)
     vset = np.unique(np.asarray(vset, dtype=np.int32))

@@ -4,6 +4,10 @@ from .pred_filter import (
     block_bounds,
     pred_filter,
     pred_filter_batch,
+)
+from .ref import (
+    pred_filter_batch_ref,
+    pred_filter_batch_xla,
+    pred_filter_ref,
     search_iters,
 )
-from .ref import pred_filter_batch_ref, pred_filter_batch_xla, pred_filter_ref

@@ -60,7 +60,7 @@ def scan_mask(
     col_order: Dict[str, int],
     binding: Dict[str, object],
     use_kernel: bool = True,
-    interpret: bool = True,
+    interpret: bool = False,
     block_rows: int = 1024,
 ) -> Optional[np.ndarray]:
     """Evaluate a conjunction over a columnar slab; None if incompatible."""

@@ -32,11 +32,18 @@ the batched kernel is bit-identical to the zone-free reference.
 Membership atoms (``col IN set``) are fused into the same launch: the sorted
 per-binding value sets are concatenated into one device-resident slab
 (``set_slab``), addressed raggedly by per-``(binding, set-atom)``
-offset/length operands, and each lane runs a fixed-iteration lower-bound
-binary search over its binding's segment (:func:`_segment_member`).  The set
-slab rides the whole grid in VMEM exactly like ``kernels/membership``'s
-V-set; zone pruning extends to set atoms by searching each block's ``lo``
-bound into the segment and checking the landed element against ``hi``.
+offset/length operands.  The slab rides the whole grid in VMEM, and each
+block evaluates membership densely — a VPU compare of every value against
+each 128-key slab row, and an MXU matmul with the per-binding segment
+indicator that counts hits per binding — because the TPU compiler has no
+1-D gather for a per-lane binary search.  Zone pruning extends to set
+atoms: a binding whose set holds no key inside a block's ``[lo, hi]``
+bounds is dead for that block, and slab tiles with no such key are
+skipped.  The XLA twin (``ref.py``) keeps the binary search, which XLA
+compiles well off the TPU.
+
+Zone bounds reach the kernel as SMEM tiles of 128 blocks each, so scalar
+memory holds one tile however long the table is.
 
 Float32 columns need no kernel changes: the backend folds their bits into a
 monotone int32 total-order key (sign-fold, ``-0`` canonicalized to ``+0``)
@@ -55,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 1024
 
@@ -111,7 +119,7 @@ def pred_filter(
     thresholds: jax.Array,  # [K] int32
     atoms: Tuple[Tuple[int, int], ...],  # static (col_idx, op_code) per atom
     block_rows: int = BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool = False,
 ) -> jax.Array:
     C, N = cols.shape
     assert N % block_rows == 0, f"pad N={N} to a multiple of {block_rows}"
@@ -133,52 +141,29 @@ def pred_filter(
 # batched launch with in-grid zone-map pruning
 # --------------------------------------------------------------------------- #
 
-
-def search_iters(max_len: int) -> int:
-    """Static iteration count for :func:`_segment_member` — enough halvings
-    to collapse any segment of at most ``max_len`` elements."""
-    return max(1, int(max_len).bit_length())
+LANES = 128  # TPU vector lane width: the minor block dim must be a multiple
+SUBLANES = 8  # ... and the second-minor one a multiple of this
+_SET_TILE = SUBLANES * LANES  # set-slab keys one membership step covers
 
 
-def _lower_bound(slab, keys, seg_lo, seg_hi, iters: int):
-    """Vectorized lower bound of ``keys`` inside per-row segments of a flat
-    sorted ``slab``.
-
-    ``keys`` is ``[K, X]``; ``seg_lo``/``seg_hi`` are ``[K, 1]`` segment
-    bounds (``slab[seg_lo:seg_hi]`` sorted ascending).  Runs a fixed
-    ``iters`` halvings so the loop is static (kernel-friendly); gathers are
-    clamped so empty segments and segments ending at ``len(slab)`` stay in
-    bounds."""
-    cap = slab.shape[0] - 1
-    lo = jnp.broadcast_to(seg_lo, keys.shape).astype(jnp.int32)
-    hi = jnp.broadcast_to(seg_hi, keys.shape).astype(jnp.int32)
-    for _ in range(iters):
-        go = lo < hi
-        mid = (lo + hi) // 2
-        v = slab[jnp.minimum(mid, cap)]
-        below = jnp.logical_and(go, v < keys)
-        lo = jnp.where(below, mid + 1, lo)
-        hi = jnp.where(jnp.logical_and(go, jnp.logical_not(below)), mid, hi)
-    return lo
+def _zone_alive_all(atoms, thr, lo_ref, hi_ref, r):
+    """``[K, 1]`` bool: bindings the block's bounds cannot refute.  The
+    bounds are SMEM scalars at lane ``r`` of this step's ``[A+M, LANES]``
+    tile (one tile serves LANES consecutive grid steps)."""
+    alive = jnp.ones((thr.shape[0], 1), jnp.bool_)
+    for j, (_, op) in enumerate(atoms):
+        alive = jnp.logical_and(
+            alive, _zone_alive(op, lo_ref[j, r], hi_ref[j, r], thr[:, j:j + 1]))
+    return alive
 
 
-def _segment_member(slab, keys, seg_lo, seg_hi, iters: int):
-    """``keys[k, x] in slab[seg_lo[k]:seg_hi[k]]`` — ``[K, X]`` bool."""
-    cap = slab.shape[0] - 1
-    pos = _lower_bound(slab, keys, seg_lo, seg_hi, iters)
-    hit = slab[jnp.minimum(pos, cap)] == keys
-    return jnp.logical_and(pos < seg_hi, hit)
-
-
-def _set_zone_alive(slab, blk_lo, blk_hi, seg_lo, seg_hi, iters: int):
-    """Can any element of each binding's set fall inside ``[blk_lo,
-    blk_hi]``?  Lower-bound the block's ``lo`` into the segment and check the
-    landed element against ``hi`` — exact, like the cmp-atom zone check."""
-    cap = slab.shape[0] - 1
-    keys = jnp.broadcast_to(blk_lo, seg_lo.shape).astype(jnp.int32)
-    pos = _lower_bound(slab, keys, seg_lo, seg_hi, iters)
-    inside = slab[jnp.minimum(pos, cap)] <= blk_hi
-    return jnp.logical_and(pos < seg_hi, inside)
+def _cmp_acc(atoms, thr, cols_ref, shape):
+    acc = jnp.ones(shape, jnp.bool_)
+    for j, (ci, op) in enumerate(atoms):
+        # one read per column for all K bindings
+        acc = jnp.logical_and(
+            acc, _apply_op(op, cols_ref[ci:ci + 1, :], thr[:, j:j + 1]))
+    return acc
 
 
 def _kernel_batch(cols_ref, thr_ref, lo_ref, hi_ref, out_ref, *,
@@ -189,86 +174,128 @@ def _kernel_batch(cols_ref, thr_ref, lo_ref, hi_ref, out_ref, *,
     thresholds first; bindings the bounds refute are masked out, and when
     *no* binding survives the block's columns are never streamed through the
     compare pipeline — the tile is just zeroed (``pl.when`` early-out)."""
-    K = thr_ref.shape[0]
-    alive = jnp.ones((K,), jnp.bool_)
-    for j, (_, op) in enumerate(atoms):
-        alive = jnp.logical_and(
-            alive, _zone_alive(op, lo_ref[j, 0], hi_ref[j, 0], thr_ref[:, j])
-        )
+    thr = thr_ref[...]
+    alive = _zone_alive_all(atoms, thr, lo_ref, hi_ref,
+                            pl.program_id(0) % LANES)
     any_alive = jnp.any(alive)
 
     @pl.when(any_alive)
     def _eval():
-        acc = jnp.ones((K, cols_ref.shape[1]), jnp.bool_)
-        for j, (ci, op) in enumerate(atoms):
-            col = cols_ref[ci, :]  # one read per column for all K bindings
-            acc = jnp.logical_and(
-                acc, _apply_op(op, col[None, :], thr_ref[:, j][:, None])
-            )
-        out_ref[...] = jnp.logical_and(acc, alive[:, None]).astype(jnp.int32)
+        acc = _cmp_acc(atoms, thr, cols_ref, out_ref.shape)
+        out_ref[...] = jnp.logical_and(acc, alive).astype(jnp.int32)
 
     @pl.when(jnp.logical_not(any_alive))
     def _skip():
         out_ref[...] = jnp.zeros_like(out_ref)
+
+
+def _in_segment(tile_row, sub: int, seg_lo, seg_hi):
+    """``[K, LANES]`` bool: which bindings' segments hold the slab keys of
+    row ``sub`` of set-slab tile ``tile_row``."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+    pos = (tile_row * SUBLANES + sub) * LANES + lane
+    return jnp.logical_and(pos >= seg_lo, pos < seg_hi)
 
 
 def _kernel_batch_sets(cols_ref, thr_ref, lo_ref, hi_ref, set_slab_ref,
-                       set_off_ref, set_len_ref, out_ref, *,
+                       set_off_ref, set_len_ref, out_ref, cnt_ref, *,
                        atoms: Tuple[Tuple[int, int], ...],
-                       set_cols: Tuple[int, ...], iters: int):
+                       set_cols: Tuple[int, ...]):
     """Set-carrying variant of :func:`_kernel_batch`.
 
     The zone-bound operands carry ``A + M`` rows: the first ``A`` belong to
-    the cmp atoms, the trailing ``M`` to the set atoms' columns.  Set atoms
-    participate in the in-grid prune (a block dies for a binding whose set
-    has no element inside the block's bounds) and, for surviving blocks,
-    each lane lower-bound-searches its binding's sorted segment of the
-    VMEM-resident set slab."""
-    K = thr_ref.shape[0]
+    the cmp atoms, the trailing ``M`` to the set atoms' columns.  The set
+    slab is ``[R, LANES]`` (keys in lanes, resident in VMEM for the whole
+    grid); binding ``k``'s set for atom ``m`` is the flat key range
+    ``[off[k, m], off[k, m] + len[k, m])``.
+
+    Membership is a dense compare on the VPU plus a segment matmul on the
+    MXU, with no gathers: for each 128-key slab row, ``eq[key, row]`` is
+    the key-vs-value compare and ``seg[k, key]`` says whether the key
+    belongs to binding ``k``'s set; ``seg @ eq`` counts each binding's hits
+    per row (exact — keys within a segment are unique, the 0/1 operands
+    are exact in bf16 and the sums in f32).  Slab tiles holding no key
+    inside the block's ``[lo, hi]`` are skipped.  Set atoms also join the
+    in-grid prune: a block dies for a binding whose set has no key inside
+    the block's bounds."""
+    thr = thr_ref[...]
+    K = thr.shape[0]
     A = len(atoms)
-    slab = set_slab_ref[...]
-    alive = jnp.ones((K,), jnp.bool_)
-    for j, (_, op) in enumerate(atoms):
-        alive = jnp.logical_and(
-            alive, _zone_alive(op, lo_ref[j, 0], hi_ref[j, 0], thr_ref[:, j])
-        )
+    r = pl.program_id(0) % LANES
+    n_tiles = set_slab_ref.shape[0] // SUBLANES
+    off = set_off_ref[...]
+    ln = set_len_ref[...]
+
+    def tile(g):
+        return set_slab_ref[pl.ds(pl.multiple_of(g * SUBLANES, SUBLANES),
+                                  SUBLANES), :]
+
+    alive = _zone_alive_all(atoms, thr, lo_ref, hi_ref, r)
     for m in range(len(set_cols)):
-        seg_lo = set_off_ref[:, m][:, None]
-        seg_hi = seg_lo + set_len_ref[:, m][:, None]
-        alive = jnp.logical_and(
-            alive,
-            _set_zone_alive(slab, lo_ref[A + m, 0], hi_ref[A + m, 0],
-                            seg_lo, seg_hi, iters)[:, 0],
-        )
+        b_lo, b_hi = lo_ref[A + m, r], hi_ref[A + m, r]
+        seg_lo = off[:, m:m + 1]
+        seg_hi = seg_lo + ln[:, m:m + 1]
+
+        def zone_step(g, hit):
+            keys = tile(g)
+            inside = jnp.logical_and(keys >= b_lo, keys <= b_hi)
+            for sub in range(SUBLANES):
+                both = jnp.logical_and(_in_segment(g, sub, seg_lo, seg_hi),
+                                       inside[sub:sub + 1, :])
+                # int32 carry: Mosaic cannot carry i1 vectors through a loop
+                hit = jnp.maximum(hit, jnp.max(both.astype(jnp.int32), axis=1,
+                                               keepdims=True))
+            return hit
+
+        hit = jax.lax.fori_loop(0, n_tiles, zone_step,
+                                jnp.zeros((K, 1), jnp.int32))
+        alive = jnp.logical_and(alive, hit > 0)
     any_alive = jnp.any(alive)
 
     @pl.when(any_alive)
     def _eval():
-        acc = jnp.ones((K, cols_ref.shape[1]), jnp.bool_)
-        for j, (ci, op) in enumerate(atoms):
-            col = cols_ref[ci, :]
-            acc = jnp.logical_and(
-                acc, _apply_op(op, col[None, :], thr_ref[:, j][:, None])
-            )
+        acc = _cmp_acc(atoms, thr, cols_ref, out_ref.shape)
         for m, ci in enumerate(set_cols):
-            col = cols_ref[ci, :]
-            seg_lo = set_off_ref[:, m][:, None]
-            seg_hi = seg_lo + set_len_ref[:, m][:, None]
-            acc = jnp.logical_and(
-                acc,
-                _segment_member(slab, jnp.broadcast_to(col[None, :], acc.shape),
-                                seg_lo, seg_hi, iters),
-            )
-        out_ref[...] = jnp.logical_and(acc, alive[:, None]).astype(jnp.int32)
+            col = cols_ref[ci:ci + 1, :]
+            b_lo, b_hi = lo_ref[A + m, r], hi_ref[A + m, r]
+            seg_lo = off[:, m:m + 1]
+            seg_hi = seg_lo + ln[:, m:m + 1]
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+            @pl.loop(0, n_tiles)
+            def _member_step(g):
+                keys = tile(g)
+                inside = jnp.logical_and(keys >= b_lo, keys <= b_hi)
+
+                @pl.when(jnp.any(inside))
+                def _count():
+                    keys_t = keys.T  # [LANES, SUBLANES]: keys down sublanes
+                    for sub in range(SUBLANES):
+                        eq = (keys_t[:, sub:sub + 1] == col).astype(jnp.bfloat16)
+                        seg = _in_segment(g, sub, seg_lo, seg_hi).astype(
+                            jnp.bfloat16)
+                        cnt_ref[...] += jnp.dot(
+                            seg, eq, preferred_element_type=jnp.float32)
+
+            acc = jnp.logical_and(acc, cnt_ref[...] > 0)
+        out_ref[...] = jnp.logical_and(acc, alive).astype(jnp.int32)
 
     @pl.when(jnp.logical_not(any_alive))
     def _skip():
         out_ref[...] = jnp.zeros_like(out_ref)
 
 
+def _pad_to(x, axis: int, multiple: int):
+    pad = (-x.shape[axis]) % multiple
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths)
+
+
 @functools.partial(
-    jax.jit,
-    static_argnames=("atoms", "block_rows", "interpret", "set_cols", "iters"))
+    jax.jit, static_argnames=("atoms", "block_rows", "interpret", "set_cols"))
 def pred_filter_batch(
     cols: jax.Array,  # [C, N] int32 columnar slab, N % block_rows == 0
     thresholds: jax.Array,  # [K, A] int32 — K bindings x A atoms
@@ -276,53 +303,57 @@ def pred_filter_batch(
     blk_lo: jax.Array,  # [A(+M), G] int32 per-(atom, block) lower bounds
     blk_hi: jax.Array,  # [A(+M), G] int32 per-(atom, block) upper bounds
     block_rows: int = BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool = False,
     set_cols: Tuple[int, ...] = (),  # static col idx per membership atom
     set_slab: jax.Array = None,  # [S] int32 concatenated sorted sets
     set_off: jax.Array = None,  # [K, M] int32 segment offsets into set_slab
     set_len: jax.Array = None,  # [K, M] int32 segment lengths
-    iters: int = 1,  # static search depth: search_iters(max set len)
 ) -> jax.Array:  # [K, N] int32 masks
     C, N = cols.shape
     K, A = thresholds.shape
     M = len(set_cols)
+    G = N // block_rows
     assert N % block_rows == 0, f"pad N={N} to a multiple of {block_rows}"
-    assert A == len(atoms) and blk_lo.shape == blk_hi.shape == (A + M, N // block_rows)
-    if not set_cols:
+    assert A == len(atoms) and blk_lo.shape == blk_hi.shape == (A + M, G)
+    # zone bounds ride in SMEM as [A+M, LANES] tiles, one tile per LANES
+    # grid steps: lane-aligned blocks the TPU compiler accepts, and SMEM
+    # holds only the current tile however long the table is
+    blk_lo, blk_hi = _pad_to(blk_lo, 1, LANES), _pad_to(blk_hi, 1, LANES)
+    bounds = pl.BlockSpec((A + M, LANES), lambda i: (0, i // LANES),
+                          memory_space=pltpu.SMEM)
+    in_specs = [
+        pl.BlockSpec((C, block_rows), lambda i: (0, i)),  # column slab
+        pl.BlockSpec((K, A), lambda i: (0, 0)),  # thresholds (all bindings)
+        bounds,  # this block's lo bounds
+        bounds,  # this block's hi bounds
+    ]
+    operands = [cols, thresholds, blk_lo, blk_hi]
+    scratch = []
+    if set_cols:
+        assert set_off.shape == set_len.shape == (K, M)
+        # keys in lanes, whole slab resident in VMEM; padding keys sit past
+        # every segment, so no binding counts them
+        slab = _pad_to(set_slab, 0, _SET_TILE).reshape(-1, LANES)
+        in_specs += [
+            pl.BlockSpec(slab.shape, lambda i: (0, 0)),  # set slab
+            pl.BlockSpec((K, M), lambda i: (0, 0)),  # segment offsets
+            pl.BlockSpec((K, M), lambda i: (0, 0)),  # segment lengths
+        ]
+        operands += [slab, set_off, set_len]
+        scratch = [pltpu.VMEM((K, block_rows), jnp.float32)]  # hit counts
+        kern = functools.partial(_kernel_batch_sets, atoms=atoms,
+                                 set_cols=set_cols)
+    else:
         kern = functools.partial(_kernel_batch, atoms=atoms)
-        return pl.pallas_call(
-            kern,
-            out_shape=jax.ShapeDtypeStruct((K, N), jnp.int32),
-            grid=(N // block_rows,),
-            in_specs=[
-                pl.BlockSpec((C, block_rows), lambda i: (0, i)),  # column slab
-                pl.BlockSpec((K, A), lambda i: (0, 0)),  # thresholds (all bindings)
-                pl.BlockSpec((A, 1), lambda i: (0, i)),  # this block's lo bounds
-                pl.BlockSpec((A, 1), lambda i: (0, i)),  # this block's hi bounds
-            ],
-            out_specs=pl.BlockSpec((K, block_rows), lambda i: (0, i)),
-            interpret=interpret,
-        )(cols, thresholds, blk_lo, blk_hi)
-    (S,) = set_slab.shape
-    assert set_off.shape == set_len.shape == (K, M)
-    kern = functools.partial(_kernel_batch_sets, atoms=atoms,
-                             set_cols=set_cols, iters=iters)
     return pl.pallas_call(
         kern,
         out_shape=jax.ShapeDtypeStruct((K, N), jnp.int32),
-        grid=(N // block_rows,),
-        in_specs=[
-            pl.BlockSpec((C, block_rows), lambda i: (0, i)),  # column slab
-            pl.BlockSpec((K, A), lambda i: (0, 0)),  # thresholds (all bindings)
-            pl.BlockSpec((A + M, 1), lambda i: (0, i)),  # block lo bounds
-            pl.BlockSpec((A + M, 1), lambda i: (0, i)),  # block hi bounds
-            pl.BlockSpec((S,), lambda i: (0,)),  # whole set slab in VMEM
-            pl.BlockSpec((K, M), lambda i: (0, 0)),  # segment offsets
-            pl.BlockSpec((K, M), lambda i: (0, 0)),  # segment lengths
-        ],
+        grid=(G,),
+        in_specs=in_specs,
         out_specs=pl.BlockSpec((K, block_rows), lambda i: (0, i)),
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(cols, thresholds, blk_lo, blk_hi, set_slab, set_off, set_len)
+    )(*operands)
 
 
 def block_bounds(slab: np.ndarray, block_rows: int,

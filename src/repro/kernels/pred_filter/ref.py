@@ -15,7 +15,40 @@ from typing import Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from .pred_filter import _segment_member
+
+def search_iters(max_len: int) -> int:
+    """Static iteration count for :func:`_segment_member` — enough halvings
+    to collapse any segment of at most ``max_len`` elements."""
+    return max(1, int(max_len).bit_length())
+
+
+def _lower_bound(slab, keys, seg_lo, seg_hi, iters: int):
+    """Vectorized lower bound of ``keys`` inside per-row segments of a flat
+    sorted ``slab``.
+
+    ``keys`` is ``[K, X]``; ``seg_lo``/``seg_hi`` are ``[K, 1]`` segment
+    bounds (``slab[seg_lo:seg_hi]`` sorted ascending).  Runs a fixed
+    ``iters`` halvings so the loop is static; gathers are clamped so empty
+    segments and segments ending at ``len(slab)`` stay in bounds."""
+    cap = slab.shape[0] - 1
+    lo = jnp.broadcast_to(seg_lo, keys.shape).astype(jnp.int32)
+    hi = jnp.broadcast_to(seg_hi, keys.shape).astype(jnp.int32)
+    for _ in range(iters):
+        go = lo < hi
+        mid = (lo + hi) // 2
+        v = slab[jnp.minimum(mid, cap)]
+        below = jnp.logical_and(go, v < keys)
+        lo = jnp.where(below, mid + 1, lo)
+        hi = jnp.where(jnp.logical_and(go, jnp.logical_not(below)), mid, hi)
+    return lo
+
+
+def _segment_member(slab, keys, seg_lo, seg_hi, iters: int):
+    """``keys[k, x] in slab[seg_lo[k]:seg_hi[k]]`` — ``[K, X]`` bool."""
+    cap = slab.shape[0] - 1
+    pos = _lower_bound(slab, keys, seg_lo, seg_hi, iters)
+    hit = slab[jnp.minimum(pos, cap)] == keys
+    return jnp.logical_and(pos < seg_hi, hit)
 
 
 def _cmp(col, t, op: int):

@@ -7,6 +7,11 @@ rate, and latency percentiles:
   PYTHONPATH=src python -m repro.launch.lineage_serve --smoke
   PYTHONPATH=src python -m repro.launch.lineage_serve \\
       --sf 0.02 --clients 8 --requests 256 --queries q3,q10 --store
+  PYTHONPATH=src python -m repro.launch.lineage_serve --backend pallas
+
+``--backend pallas`` scans on the device (compiled Pallas kernel on a TPU,
+the jitted XLA twin elsewhere); the default ``numpy`` keeps scans on the
+host.
 """
 
 from __future__ import annotations
@@ -18,15 +23,18 @@ from typing import Dict, List, Tuple
 
 import numpy as np
 
-from ..core import Executor, LineageService, PredTrace
+from ..core import Executor, LineageService, PredTrace, ScanEngine
 from ..tpch import ALL_QUERIES, generate
+from .compile_cache import setup_compile_cache
 
 
-def _prepare(db, qname: str, store: bool, num_partitions) -> PredTrace:
+def _prepare(db, qname: str, store: bool, num_partitions,
+             backend: str) -> PredTrace:
     plan = ALL_QUERIES[qname](db)
     res = Executor(db).run(plan)
     pt = PredTrace(db, plan, store=store or None,
-                   num_partitions=num_partitions)
+                   num_partitions=num_partitions,
+                   scan_engine=ScanEngine(backend=backend))
     pt.infer(stats=res.stats)
     pt.run()
     return pt
@@ -45,7 +53,10 @@ def _workload(pts: Dict[str, PredTrace], n: int, zipf_a: float,
     return reqs
 
 
-def main(argv=None):
+def main(argv=None, db=None):
+    """Run the workload; returns the service stats, the requests, their
+    answers and each pipeline's scan-engine stats.  ``db`` reuses tables
+    already generated for ``--sf``/``--seed``."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--sf", type=float, default=0.01)
     ap.add_argument("--seed", type=int, default=1)
@@ -61,17 +72,22 @@ def main(argv=None):
     ap.add_argument("--store", action="store_true",
                     help="serve from compressed intermediate stores")
     ap.add_argument("--partitions", type=int, default=None)
+    ap.add_argument("--backend", choices=("numpy", "pallas"),
+                    default="numpy", help="scan backend of every pipeline")
     ap.add_argument("--smoke", action="store_true",
                     help="tiny preset: sf=0.005, 64 requests")
     args = ap.parse_args(argv)
     if args.smoke:
         args.sf, args.requests = 0.005, 64
 
-    print(f"[lineage-serve] generating TPC-H sf={args.sf} seed={args.seed}")
-    db = generate(sf=args.sf, seed=args.seed)
+    setup_compile_cache()
+    if db is None:
+        print(f"[lineage-serve] generating TPC-H sf={args.sf} "
+              f"seed={args.seed}")
+        db = generate(sf=args.sf, seed=args.seed)
     pts: Dict[str, PredTrace] = {}
     for q in args.queries.split(","):
-        pt = _prepare(db, q, args.store, args.partitions)
+        pt = _prepare(db, q, args.store, args.partitions, args.backend)
         if pt.exec_result.output.nrows:
             pts[q] = pt
     reqs = _workload(pts, args.requests, args.zipf, args.seed)
@@ -126,6 +142,7 @@ def main(argv=None):
     identical = all(key(answers[i]) == key(serial[i]) for i in range(len(reqs)))
     st = svc.stats()
     svc.close()
+    scan_stats = {q: pt.scan_engine.stats() for q, pt in pts.items()}
     for pt in pts.values():
         pt.close()
 
@@ -141,7 +158,9 @@ def main(argv=None):
           f"answered={st['answered']} expired={st['expired']} "
           f"failed={st['failed']}")
     assert identical, "service answers diverged from serial query()"
-    return st
+    return {"service": st, "requests": reqs,
+            "answers": [answers[i] for i in range(len(reqs))],
+            "scan_stats": scan_stats}
 
 
 if __name__ == "__main__":

@@ -12,9 +12,8 @@ any JAX initialization — see dryrun.py).
 
 from __future__ import annotations
 
-import jax
-
-from ..compat import AxisType, make_mesh
+from jax import make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -21,9 +21,10 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import make_mesh
+from jax.sharding import AxisType
 
 from ..checkpoint.manager import CheckpointManager
-from ..compat import AxisType, make_mesh
 from ..configs import get, smoke_config
 from ..data.pipeline import LineageDataPipeline, synth_corpus
 from ..models import model as M

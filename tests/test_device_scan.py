@@ -214,6 +214,50 @@ def test_pruned_grid_multi_binding_mixed_blocks():
     assert not np.asarray(got)[2].any() and np.asarray(got)[0].all()
 
 
+def test_pruned_grid_set_atoms_match_oracle():
+    """In-grid membership over a multi-tile set slab: ragged per-binding
+    segments (one empty, one confined to a few blocks, one spanning the
+    table) must answer exactly like the zone-free binary-search oracle,
+    with set-zone pruning and slab-tile skipping both engaged."""
+    import jax.numpy as jnp
+
+    from repro.kernels.pred_filter import search_iters
+
+    cols, _, br = _grid_case("partial")
+    rng = np.random.default_rng(60)
+    segs = [
+        np.array([], np.int32),                                   # empty
+        np.unique(rng.integers(3000, 3100, 40)).astype(np.int32),  # 1 block
+        np.unique(rng.integers(-50, 9000, 2500)).astype(np.int32),  # all
+        np.arange(0, 2000, 7, dtype=np.int32),                    # low blocks
+        np.array([5000, 6099], np.int32),  # exactly a block's min / max
+    ]
+    off = np.cumsum([0] + [s.size for s in segs[:-1]]).astype(np.int32)
+    ln = np.array([s.size for s in segs], np.int32)
+    slab = np.concatenate(segs).astype(np.int32)
+    K = len(segs)
+    thr = np.array([[0], [-1], [1000], [0], [0]], np.int32)  # col >= t
+    atoms = ((0, OPS[">="]),)
+    lo, hi = block_bounds(np.vstack([cols, cols]), br, (0, 1))
+    got = pred_filter_batch(jnp.asarray(cols), jnp.asarray(thr), atoms,
+                            jnp.asarray(lo), jnp.asarray(hi), block_rows=br,
+                            interpret=True, set_cols=(0,),
+                            set_slab=jnp.asarray(slab),
+                            set_off=jnp.asarray(off.reshape(K, 1)),
+                            set_len=jnp.asarray(ln.reshape(K, 1)))
+    want = pred_filter_batch_ref(jnp.asarray(cols), jnp.asarray(thr), atoms,
+                                 set_cols=(0,), set_slab=jnp.asarray(slab),
+                                 set_off=jnp.asarray(off.reshape(K, 1)),
+                                 set_len=jnp.asarray(ln.reshape(K, 1)),
+                                 iters=search_iters(int(ln.max())))
+    got, want = np.asarray(got), np.asarray(want)
+    assert np.array_equal(got, want)
+    for k, s in enumerate(segs):
+        assert np.array_equal(got[k] != 0,
+                              np.isin(cols[0], s) & (cols[0] >= thr[k, 0]))
+    assert not got[0].any() and got[2].any()
+
+
 @pytest.mark.parametrize("n", [1, 1000, 1024, 1025, 4097])
 def test_ragged_row_counts(n):
     """Row counts off the block boundary: slab padding must never leak
@@ -639,3 +683,44 @@ def test_store_dispatch_prefers_insitu_rle():
     assert eng.stats.rle_insitu_chosen >= 1
     assert eng.stats.rle_run_scans >= 1
     assert eng.stats.decode_chosen == 0
+
+
+# --------------------------------------------------------------------------- #
+# device failures surface; they never become host answers
+# --------------------------------------------------------------------------- #
+def _raising_launch(*_args, **_kw):
+    raise RuntimeError("device launch failed")
+
+
+@pytest.mark.parametrize("probe", ["device_scan_probe", "member_scan_probe",
+                                   "rle_scan_probe"])
+def test_probe_launch_failure_propagates(probe, monkeypatch):
+    from repro.core import dispatch
+
+    for env in ("PREDTRACE_DEVICE_CUTOVER", "PREDTRACE_MEMBER_CUTOVER",
+                "PREDTRACE_RLE_CUTOVER"):
+        monkeypatch.delenv(env, raising=False)
+    key = f"test-raising-{probe}"
+    with pytest.raises(RuntimeError, match="device launch failed"):
+        getattr(dispatch, probe)(key, _raising_launch)
+    assert key not in dispatch.probe_info()[probe.split("_")[0]]
+
+
+def test_mesh_scan_launch_failure_propagates():
+    import jax
+
+    from repro.core.distributed import PartitionExecutor
+
+    mesh = jax.make_mesh((1,), ("data",))
+    eng = ScanEngine()
+    eng.jit_scan = lambda pred: _raising_launch
+    ex = PartitionExecutor(eng, mesh=mesh, mesh_axes=("data",), max_workers=0)
+    t = Table({"a": np.arange(N, dtype=np.int32)}, {}, "t")
+    with pytest.raises(RuntimeError, match="device launch failed"):
+        ex.scan(Col("a") >= Param("p"), t, {"p": 7})
+    # outside the exact int32 fragment the host answers, by decision
+    launched = eng.stats.mesh_scans
+    tf = Table({"f": np.linspace(0, 1, N)}, {}, "tf")
+    got = ex.scan(Col("f") >= Param("p"), tf, {"p": 0.5})
+    assert np.array_equal(got, tf.cols["f"] >= 0.5)
+    assert eng.stats.mesh_scans == launched

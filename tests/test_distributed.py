@@ -142,3 +142,39 @@ def test_multipod_mesh_lowering_smoke():
         print("MULTIPOD_OK")
     """))
     assert "MULTIPOD_OK" in out
+
+
+def test_mesh_scan_is_int32_exact():
+    """The mesh path holds int32 on the device: a padded IN set must not
+    match key-0 rows, a float64 column must not be compared in float32, and
+    the columns must really be spread over every device."""
+    out = run_sub(textwrap.dedent("""
+        import numpy as np
+        from repro.core import ScanEngine
+        from repro.core.distributed import PartitionExecutor
+        from repro.core.expr import Col, IsIn, Param, land
+        from repro.core.table import Table
+        from repro.launch.mesh import make_host_mesh
+
+        n = 4000
+        k = (np.arange(n) % 50).astype(np.int64)         # key 0 present
+        price = 1000.0 + np.arange(n) * 1e-6             # float32 merges these
+        t = Table({"k": k, "price": price}, {}, "t")
+        eng = ScanEngine()
+        ex = PartitionExecutor(eng, mesh=make_host_mesh(data=4, model=1),
+                               max_workers=0)
+        s = np.array([3, 7, 9], np.int64)                # pads to 4 slots
+        m = ex.scan(IsIn(Col("k"), Param("s")), t, {"s": s})
+        assert np.array_equal(m, np.isin(k, s)) and not m[k == 0].any()
+        m = ex.scan(Col("k").eq(Param("s")), t, {"s": np.array([], np.int64)})
+        assert not m.any()
+        assert eng.stats.mesh_scans == 2
+        p = float(price[1234])
+        pred = land(Col("price") < Param("p"), Col("k") >= Param("q"))
+        m = ex.scan(pred, t, {"p": p, "q": 1})
+        assert np.array_equal(m, (price < p) & (k >= 1))
+        assert eng.stats.mesh_scans == 2, "float64 compare reached the device"
+        assert ex.placement() == {"t": {0, 1, 2, 3}}, ex.placement()
+        print("MESH_EXACT_OK")
+    """), devices=4)
+    assert "MESH_EXACT_OK" in out

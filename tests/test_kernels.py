@@ -26,7 +26,8 @@ def test_pred_filter_sweep(n_rows, n_atoms):
         (int(rng.integers(0, 8)), int(rng.integers(0, 6))) for _ in range(n_atoms)
     )
     thr = rng.integers(-50, 50, n_atoms).astype(np.int32)
-    out = pred_filter(jnp.asarray(cols), jnp.asarray(thr), atoms, block_rows=512)
+    out = pred_filter(jnp.asarray(cols), jnp.asarray(thr), atoms, block_rows=512,
+                      interpret=True)
     ref = pred_filter_ref(jnp.asarray(cols), jnp.asarray(thr), atoms)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
@@ -37,7 +38,7 @@ def test_pred_filter_from_expr():
     cols = rng.integers(0, 100, (3, 1000)).astype(np.int32)
     pred = land(Col("a") >= 10, Col("b") < 50, Col("c").eq(Param("v")))
     order = {"a": 0, "b": 1, "c": 2}
-    m = scan_mask(cols, pred, order, {"v": 7})
+    m = scan_mask(cols, pred, order, {"v": 7}, interpret=True)
     want = (cols[0] >= 10) & (cols[1] < 50) & (cols[2] == 7)
     np.testing.assert_array_equal(m, want)
 
@@ -59,13 +60,13 @@ def test_pred_filter_incompatible_returns_none():
 def test_membership_sweep(n, m):
     vals = rng.integers(0, 10_000, n).astype(np.int32)
     vset = rng.choice(10_000, m, replace=False).astype(np.int32)
-    got = probe(vals, vset)
+    got = probe(vals, vset, interpret=True)
     np.testing.assert_array_equal(got, np.isin(vals, vset))
 
 
 def test_membership_empty_set():
     vals = rng.integers(0, 10, 100).astype(np.int32)
-    assert probe(vals, np.array([], np.int32)).sum() == 0
+    assert probe(vals, np.array([], np.int32), interpret=True).sum() == 0
 
 
 # --------------------------------------------------------------------------- #
@@ -79,7 +80,7 @@ def test_flash_attention_sweep(s, d, window, dtype):
     q = jnp.asarray(rng.standard_normal((2, s, d)), dtype)
     k = jnp.asarray(rng.standard_normal((2, s, d)), dtype)
     v = jnp.asarray(rng.standard_normal((2, s, d)), dtype)
-    o = flash_attention(q, k, v, window=window, bq=128, bk=128)
+    o = flash_attention(q, k, v, window=window, bq=128, bk=128, interpret=True)
     r = attention_ref(q, k, v, window=window)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(
@@ -93,7 +94,8 @@ def test_flash_mha_layout():
     k = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, H, D)), jnp.float32)
     np.testing.assert_allclose(
-        np.asarray(mha_flash(q, k, v)), np.asarray(mha_ref(q, k, v)), rtol=2e-5, atol=2e-5
+        np.asarray(mha_flash(q, k, v, interpret=True)), np.asarray(mha_ref(q, k, v)),
+        rtol=2e-5, atol=2e-5
     )
 
 
@@ -114,7 +116,8 @@ def test_flash_matches_model_attention():
     n_rep = cfg.n_heads // cfg.n_kv_heads
     k, v = ML._expand_kv(k, n_rep), ML._expand_kv(v, n_rep)
     out_kernel = jnp.einsum(
-        "bqhd,hdo->bqo", mha_flash(q, k, v, window=cfg.sliding_window),
+        "bqhd,hdo->bqo",
+        mha_flash(q, k, v, window=cfg.sliding_window, interpret=True),
         p["wo"].astype(x.dtype),
     )
     np.testing.assert_allclose(

@@ -21,6 +21,7 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple, Union
 import numpy as np
 
 from . import ops as O
+from . import trace
 from .executor import ExecResult, Executor
 from .expr import (
     BinOp, Col, Expr, FALSE, IsIn, Param, cols_of, conjuncts, eval_np,
@@ -143,32 +144,33 @@ def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
         from .executor import composite_codes
 
         sel = stage_sel[sid]
-        atoms = []
-        for a in conjuncts(pred):
-            ap = params_of(a)
-            if len(ap) == 1 and next(iter(ap)) in plist and isinstance(a, BinOp):
-                p = next(iter(ap))
-                lhs = a.left if isinstance(a.right, Param) else a.right
-                atoms.append((lhs, np.asarray(sel.cols[param_col[p]])))
-                consumed_atoms.append(a)
-        idx = _zone_restrict(table, atoms)
-        lhs_vals = []
-        for lhs, sel_vals in atoms:
-            env = {c: table.cols[c][idx] for c in _cols_of(lhs)}
-            v = np.asarray(eval_np(lhs, env, {}, n=len(idx)))
-            # sorted-unique is hoisted out of the per-partition loop: the
-            # stage selection array is the same object every call, so the
-            # id-keyed cache sorts it once per predicate, not once per part
-            keep = np.isin(v, _sorted_unique(sel_vals))
-            idx = idx[keep]
-            lhs_vals = [lv[keep] for lv in lhs_vals]
-            lhs_vals.append(v[keep])
-        if len(atoms) > 1 and len(idx):
-            ct, cs = composite_codes(lhs_vals, [sv for _, sv in atoms])
-            idx = idx[np.isin(ct, cs)]
-        gmask = np.zeros(table.nrows, dtype=bool)
-        gmask[idx] = True
-        mask &= gmask
+        with trace.span("lineage.tuple", rows=sel.nrows):
+            atoms = []
+            for a in conjuncts(pred):
+                ap = params_of(a)
+                if len(ap) == 1 and next(iter(ap)) in plist and isinstance(a, BinOp):
+                    p = next(iter(ap))
+                    lhs = a.left if isinstance(a.right, Param) else a.right
+                    atoms.append((lhs, np.asarray(sel.cols[param_col[p]])))
+                    consumed_atoms.append(a)
+            idx = _zone_restrict(table, atoms)
+            lhs_vals = []
+            for lhs, sel_vals in atoms:
+                env = {c: table.cols[c][idx] for c in _cols_of(lhs)}
+                v = np.asarray(eval_np(lhs, env, {}, n=len(idx)))
+                # sorted-unique is hoisted out of the per-partition loop: the
+                # stage selection array is the same object every call, so the
+                # id-keyed cache sorts it once per predicate, not once per part
+                keep = np.isin(v, _sorted_unique(sel_vals))
+                idx = idx[keep]
+                lhs_vals = [lv[keep] for lv in lhs_vals]
+                lhs_vals.append(v[keep])
+            if len(atoms) > 1 and len(idx):
+                ct, cs = composite_codes(lhs_vals, [sv for _, sv in atoms])
+                idx = idx[np.isin(ct, cs)]
+            gmask = np.zeros(table.nrows, dtype=bool)
+            gmask[idx] = True
+            mask &= gmask
 
     rest = [a for a in conjuncts(pred) if a not in consumed_atoms]
     rest_params = set()
@@ -195,11 +197,12 @@ def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
     from .expr import land
 
     rest_pred = land(*rest)
-    for r in rows:
-        b2 = dict(binding)
-        for p, val in zip(plist, r):
-            b2[p] = val.item() if hasattr(val, "item") else val
-        rmask |= scan(rest_pred, table, b2)
+    with trace.span("lineage.rowwise", rows=len(rows)):
+        for r in rows:
+            b2 = dict(binding)
+            for p, val in zip(plist, r):
+                b2[p] = val.item() if hasattr(val, "item") else val
+            rmask |= scan(rest_pred, table, b2)
     return mask & rmask
 
 
@@ -711,9 +714,10 @@ class PredTrace:
         """Iterative refinement (Algorithm 3) used as the per-table fallback
         when budget-dropped stages leave source-predicate params unbound."""
         self._ensure_iter_plan()
-        binding = self._output_binding(t_o, self.iter_plan.out_params)
-        return refine(self.iter_plan, self.catalog, binding,
-                      scan=lambda p, t, b: self._scan(p, t, b))
+        with trace.span("lineage.superset"):
+            binding = self._output_binding(t_o, self.iter_plan.out_params)
+            return refine(self.iter_plan, self.catalog, binding,
+                          scan=lambda p, t, b: self._scan(p, t, b))
 
     def _stage_select(self, st: Stage, stobj, binding, param_stage, stage_sel,
                       param_col) -> Table:
@@ -721,19 +725,23 @@ class PredTrace:
         in situ when the binding shape is a plain conjunction (the common
         case) and only the selected rows are decoded via gather; the
         tuple/row-wise binding shapes fall back to the decoded table."""
-        scan = self._scan
-        if isinstance(stobj, StoredTable) and self.store is not None:
-            tg, rw = _binding_groups(st.run_pred, binding, param_stage)
-            if not tg and not rw:
-                m = self.store.scan(st.node_id, st.run_pred, binding,
-                                    self.scan_engine)
-                return stobj.take(np.nonzero(m)[0])
-            table = stobj.to_table()
-        else:
-            table = stobj
-        m = _eval_pred(st.run_pred, table, binding, param_stage, stage_sel,
-                       param_col, scan=scan)
-        return table.mask(m)
+        with trace.span("lineage.stage", node=st.node_id) as sp:
+            if isinstance(stobj, StoredTable) and self.store is not None:
+                tg, rw = _binding_groups(st.run_pred, binding, param_stage)
+                if not tg and not rw:
+                    m = self.store.scan(st.node_id, st.run_pred, binding,
+                                        self.scan_engine)
+                    sel = stobj.take(np.nonzero(m)[0])
+                    sp.set(route="in_situ", rows=sel.nrows)
+                    return sel
+                table, route = stobj.to_table(), "decoded"
+            else:
+                table, route = stobj, "plain"
+            m = _eval_pred(st.run_pred, table, binding, param_stage,
+                           stage_sel, param_col, scan=self._scan)
+            sel = table.mask(m)
+            sp.set(route=route, rows=sel.nrows)
+            return sel
 
     def query(self, t_o: Union[int, Dict[str, object]]) -> LineageAnswer:
         """Precise lineage via materialized intermediates (Algorithm 1).
@@ -742,9 +750,14 @@ class PredTrace:
         dropped stage's params degrade *per table* to the iterative/superset
         path (``detail["superset_tables"]``); everything whose stage chain is
         still materialized stays precise."""
+        with trace.span("lineage.query", rows=1):
+            return self._query(t_o)
+
+    def _query(self, t_o: Union[int, Dict[str, object]]) -> LineageAnswer:
         assert self.lineage_plan is not None and self.exec_result is not None
         t0 = time.perf_counter()
-        binding = self._output_binding(t_o)
+        with trace.span("lineage.bind"):
+            binding = self._output_binding(t_o)
         scan = self._scan
         lp = self.lineage_plan
         dropped = self.mat_plan.dropped if self.mat_plan is not None else set()
@@ -804,15 +817,18 @@ class PredTrace:
                 fallback.add(sp.table)  # unbound params: superset path below
                 continue
             t = self.catalog[sp.table]
-            if sp.pred == FALSE or any(_guard_dead(binding.get(g)) for g in sp.guards):
-                rids = np.array([], dtype=np.int64)
-            else:
-                m = _eval_pred(sp.pred, t, binding, param_stage, stage_sel,
-                               param_col, scan=scan)
-                rids = t.rids()[m]
-            lineage[sp.table] = (
-                np.union1d(lineage[sp.table], rids) if sp.table in lineage else np.unique(rids)
-            )
+            with trace.span("lineage.source", table=sp.table) as ts:
+                if sp.pred == FALSE or any(_guard_dead(binding.get(g)) for g in sp.guards):
+                    rids = np.array([], dtype=np.int64)
+                else:
+                    m = _eval_pred(sp.pred, t, binding, param_stage, stage_sel,
+                                   param_col, scan=scan)
+                    rids = t.rids()[m]
+                lineage[sp.table] = (
+                    np.union1d(lineage[sp.table], rids)
+                    if sp.table in lineage else np.unique(rids)
+                )
+                ts.set(rids=len(rids))
         if fallback:
             rr = self._superset_refine(t_o)
             for tab in sorted(fallback):
@@ -1164,6 +1180,12 @@ class PredTrace:
         once, equality thresholds vectorized); rows whose bindings need the
         row-wise / tuple-membership treatment fall back to the per-row
         evaluator, so answers are always identical to ``query(row)``."""
+        with trace.span("lineage.query_batch", rows=len(rows)):
+            return self._query_batch(rows)
+
+    def _query_batch(
+        self, rows: Sequence[Union[int, Dict[str, object]]]
+    ) -> List[LineageAnswer]:
         assert self.lineage_plan is not None and self.exec_result is not None
         t0 = time.perf_counter()
         B = len(rows)
@@ -1173,7 +1195,8 @@ class PredTrace:
             # budget-degraded plans mix precise and iterative answers per
             # table; answer row-by-row (query() owns that logic)
             return [self.query(r) for r in rows]
-        bindings = [self._output_binding(r) for r in rows]
+        with trace.span("lineage.bind"):
+            bindings = [self._output_binding(r) for r in rows]
         scan = self._scan
 
         param_stage: Dict[str, int] = {}
@@ -1298,7 +1321,10 @@ class PredTrace:
                 else:
                     simple.append(b)
             for entries in tuple_groups.values():
-                res = tuple_batch(pred, table, entries)
+                with trace.span("lineage.tuple", rows=sum(
+                        len(stage_idxs[b].get(sid, empty))
+                        for b, tg in entries for sid in tg)):
+                    res = tuple_batch(pred, table, entries)
                 if res is None:
                     per_row.extend(b for b, _ in entries)
                 else:
@@ -1320,78 +1346,87 @@ class PredTrace:
             if not st.params_out:
                 continue  # certification-only stage: binds nothing
             table = self.exec_result.materialized[st.node_id]
-            if isinstance(table, StoredTable):
-                # the batch path leans on the engine's identity-keyed sorted
-                # indexes; read the store through its cached decoded view
-                table = table.to_table()
-            stage_tables[si] = table
-            idxs = batch_indices(st.run_pred, table, st.guards)
-            lens = np.fromiter(
-                (0 if idx is None else len(idx) for idx in idxs), np.int64, B
-            )
-            offs = np.zeros(B, dtype=np.int64)
-            np.cumsum(lens[:-1], out=offs[1:])
-            flat = (
-                np.concatenate([idx for idx in idxs if idx is not None and len(idx)])
-                if lens.sum() else empty
-            )
-            for b in range(B):
-                stage_idxs[b][si] = empty if idxs[b] is None else idxs[b]
-            for p, colname in st.params_out.items():
-                if colname not in table.cols:
-                    continue
-                param_stage[p] = si
-                param_col[p] = colname
-                col = table.cols[colname]
-                colf = col[flat]
-                nonempty = np.nonzero(lens)[0]
-                if len(nonempty):
-                    # segment min == max detects the common constant-column
-                    # case without a per-row unique.  reduceat runs over the
-                    # non-empty segments' offsets only: they are strictly
-                    # increasing and in range, and consecutive non-empty
-                    # offsets are exact segment boundaries (empty segments
-                    # contribute no elements), so no clipping is needed —
-                    # clipping would shift the last segment's boundary.
-                    mins = np.minimum.reduceat(colf, offs[nonempty])
-                    maxs = np.maximum.reduceat(colf, offs[nonempty])
-                    seg = np.full(B, -1, dtype=np.int64)
-                    seg[nonempty] = np.arange(len(nonempty))
-                fkind = col.dtype.kind == "f"
-                ikind = col.dtype.kind in "iu"
+            stored = isinstance(table, StoredTable)
+            with trace.span("lineage.stage", node=st.node_id,
+                            route="decoded" if stored else "plain") as ss:
+                if stored:
+                    # the batch path leans on the engine's identity-keyed
+                    # sorted indexes; read the store through its cached
+                    # decoded view
+                    table = table.to_table()
+                stage_tables[si] = table
+                idxs = batch_indices(st.run_pred, table, st.guards)
+                lens = np.fromiter(
+                    (0 if idx is None else len(idx) for idx in idxs), np.int64, B
+                )
+                ss.set(rows=int(lens.sum()))
+                offs = np.zeros(B, dtype=np.int64)
+                np.cumsum(lens[:-1], out=offs[1:])
+                flat = (
+                    np.concatenate([idx for idx in idxs if idx is not None and len(idx)])
+                    if lens.sum() else empty
+                )
                 for b in range(B):
-                    ln = lens[b]
-                    if ln == 0:
-                        bindings[b][p] = col[:0]
-                    elif ln == 1 or mins[seg[b]] == maxs[seg[b]]:  # constant
-                        v = colf[offs[b]]
-                        if (fkind and np.isnan(v)) or (ikind and v == -1):
-                            bindings[b][p] = col[:0]  # null sentinel: dead
+                    stage_idxs[b][si] = empty if idxs[b] is None else idxs[b]
+                for p, colname in st.params_out.items():
+                    if colname not in table.cols:
+                        continue
+                    param_stage[p] = si
+                    param_col[p] = colname
+                    col = table.cols[colname]
+                    colf = col[flat]
+                    nonempty = np.nonzero(lens)[0]
+                    if len(nonempty):
+                        # segment min == max detects the common constant-column
+                        # case without a per-row unique.  reduceat runs over the
+                        # non-empty segments' offsets only: they are strictly
+                        # increasing and in range, and consecutive non-empty
+                        # offsets are exact segment boundaries (empty segments
+                        # contribute no elements), so no clipping is needed —
+                        # clipping would shift the last segment's boundary.
+                        mins = np.minimum.reduceat(colf, offs[nonempty])
+                        maxs = np.maximum.reduceat(colf, offs[nonempty])
+                        seg = np.full(B, -1, dtype=np.int64)
+                        seg[nonempty] = np.arange(len(nonempty))
+                    fkind = col.dtype.kind == "f"
+                    ikind = col.dtype.kind in "iu"
+                    for b in range(B):
+                        ln = lens[b]
+                        if ln == 0:
+                            bindings[b][p] = col[:0]
+                        elif ln == 1 or mins[seg[b]] == maxs[seg[b]]:  # constant
+                            v = colf[offs[b]]
+                            if (fkind and np.isnan(v)) or (ikind and v == -1):
+                                bindings[b][p] = col[:0]  # null sentinel: dead
+                            else:
+                                bindings[b][p] = v.item()
                         else:
-                            bindings[b][p] = v.item()
-                    else:
-                        bindings[b][p] = _clean_binding_value(
-                            np.unique(colf[offs[b]:offs[b] + ln])
-                        )
+                            bindings[b][p] = _clean_binding_value(
+                                np.unique(colf[offs[b]:offs[b] + ln])
+                            )
 
         lineages: List[Dict[str, np.ndarray]] = [{} for _ in range(B)]
         for sp in self.lineage_plan.source_preds:
             t = self.catalog[sp.table]
-            if sp.pred == FALSE:
-                idxs = [None] * B
-            else:
-                idxs = batch_indices(sp.pred, t, sp.guards)
-            for b in range(B):
-                idx = idxs[b]
-                rids = empty if idx is None else t.rids()[idx]
-                lin = lineages[b]
-                if sp.table in lin:
-                    lin[sp.table] = np.union1d(lin[sp.table], rids)
+            with trace.span("lineage.source", table=sp.table) as ts:
+                if sp.pred == FALSE:
+                    idxs = [None] * B
                 else:
-                    # candidate indices are distinct by construction; rids of
-                    # a source table are unique per row — sort suffices
-                    rids.sort()
-                    lin[sp.table] = rids
+                    idxs = batch_indices(sp.pred, t, sp.guards)
+                n_rids = 0
+                for b in range(B):
+                    idx = idxs[b]
+                    rids = empty if idx is None else t.rids()[idx]
+                    n_rids += len(rids)
+                    lin = lineages[b]
+                    if sp.table in lin:
+                        lin[sp.table] = np.union1d(lin[sp.table], rids)
+                    else:
+                        # candidate indices are distinct by construction; rids of
+                        # a source table are unique per row — sort suffices
+                        rids.sort()
+                        lin[sp.table] = rids
+                ts.set(rids=n_rids)
         dt = time.perf_counter() - t0
         out = []
         for b in range(B):

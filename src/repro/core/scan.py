@@ -51,6 +51,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from . import trace
 from .expr import (
     BinOp,
     Col,
@@ -178,6 +179,15 @@ class LRUCache:
     def pop(self, k, default=None):
         with self._lock:
             return self._d.pop(k, default)
+
+    def pop_anchored(self, k, ref) -> None:
+        """Drop ``k`` only while its entry is ``(ref, ...)``: the weakref
+        callback of a dead anchor must not evict a newer entry under the
+        same key."""
+        with self._lock:
+            entry = self._d.get(k)
+            if entry is not None and entry[0] is ref:
+                del self._d[k]
 
     def __contains__(self, k) -> bool:
         with self._lock:
@@ -918,10 +928,24 @@ class PallasBackend(NumpyBackend):
         self._rle_cutover = device_cutover
         self._rle_confidence = 1.0
         self._bench_slabs: Dict = {}  # cutover-measurement slabs (tiny)
+        # launch shapes that have run once: the first launch of a shape
+        # traces and compiles, and a slab build uploads — one-time costs a
+        # timed scan must not teach the cost model (colds)
+        self._warm: set = set()
+        self._cold = threading.local()
 
     def caches(self) -> Dict[str, LRUCache]:
         return {"slabs": self._slabs, "col_ok": self._col_ok,
                 "sets": self._sets}
+
+    def colds(self) -> int:
+        """One-time costs (a launch shape's first run, a slab upload) this
+        thread has paid: a timed scan across which it moved is not a
+        steady-state observation."""
+        return getattr(self._cold, "n", 0)
+
+    def _paid_cold(self) -> None:
+        self._cold.n = self.colds() + 1
 
     def attach_stats(self, stats) -> None:
         """Called by the owning ScanEngine so device launches land in its
@@ -1145,8 +1169,11 @@ class PallasBackend(NumpyBackend):
                 kernel_cmp = []
                 fallback_isin = [a for a, _ in kernel_isin] + fallback_isin
                 kernel_isin = []
+        dev = bool((kernel_cmp or kernel_isin) and n)
+        trace.note(route=route if dev else "serial")
+        colds = self.colds()
         t0 = time.perf_counter() if ch is not None else 0.0
-        if (kernel_cmp or kernel_isin) and n:
+        if dev:
             mask &= self._kernel_scan(kernel_cmp, table, binding,
                                       isin=kernel_isin)
         for a in fallback_cmp:
@@ -1157,7 +1184,7 @@ class PallasBackend(NumpyBackend):
             if r is not None:
                 mask &= np.asarray(eval_np(r, table.cols, binding, n=n), bool)
         if ch is not None:
-            ch.done(time.perf_counter() - t0)
+            ch.done(time.perf_counter() - t0, observe=self.colds() == colds)
         return mask
 
     def scan_batch_fused(self, prog: AtomProgram, table: Table,
@@ -1754,6 +1781,9 @@ class PallasBackend(NumpyBackend):
         padded = np.pad(slab, ((0, 0), (0, pad))) if pad else slab
         lo, hi = block_bounds(padded, self.block_rows,
                               tuple(range(padded.shape[0])))
+        self._paid_cold()
+        if self._stats is not None:
+            self._stats.bump(h2d_bytes=padded.nbytes)
         return _KernelSlab(jnp.asarray(padded), lo, hi, n)
 
     def _table_lane(self, table: Table, c: str) -> np.ndarray:
@@ -1765,49 +1795,36 @@ class PallasBackend(NumpyBackend):
         return arr.astype(np.int32)
 
     def _slab_entry(self, table: Table, cols: Tuple[str, ...]) -> _KernelSlab:
-        # per-colset values carry the row watermark: a slab built before an
-        # append is never served for the grown table, even though the table's
-        # identity (uid) is stable across in-place appends
-        tk = table_uid(table)
-        n = int(table.nrows)
-        entry = self._slabs.get(tk)
-        if entry is not None and entry[0]() is table:
-            hit = entry[1].get(cols)
-            if hit is not None and hit[0] == n:
-                return hit[1]
-        slab = np.stack([self._table_lane(table, c) for c in cols])
-        built = self._build_entry(slab)
-        with self._lock:
-            entry = self._slabs.get(tk)
-            if entry is None or entry[0]() is not table:
-                # the weakref callback evicts the entry when the table dies, so
-                # dead tables don't pin their slabs for the engine's lifetime
-                ref = weakref.ref(table,
-                                  lambda _, k=tk, d=self._slabs: d.pop(k, None))
-                self._slabs[tk] = (ref, {cols: (n, built)})
-            else:
-                cur = entry[1].get(cols)
-                if cur is not None and cur[0] == n:
-                    built = cur[1]
-                else:
-                    entry[1][cols] = (n, built)
-        return built
+        return self._cached_slab(
+            table_uid(table), table, cols,
+            lambda: np.stack([self._table_lane(table, c) for c in cols]))
 
     def _stored_entry(self, st, cols: Tuple[str, ...]) -> _KernelSlab:
-        tk = ("stored", table_uid(st))
-        n = int(st.nrows)
+        return self._cached_slab(
+            ("stored", table_uid(st)), st, cols,
+            lambda: np.stack([self._stored_lane_for(st, c) for c in cols]))
+
+    def _cached_slab(self, tk, obj, cols: Tuple[str, ...],
+                     lanes: Callable[[], np.ndarray]) -> _KernelSlab:
+        """The slab of ``obj``'s ``cols``, built from ``lanes()`` on a miss.
+        Per-colset values carry the row watermark: a slab built before an
+        append is never served for the grown table, even though the table's
+        identity (uid) is stable across in-place appends."""
+        n = int(obj.nrows)
         entry = self._slabs.get(tk)
-        if entry is not None and entry[0]() is st:
+        if entry is not None and entry[0]() is obj:
             hit = entry[1].get(cols)
             if hit is not None and hit[0] == n:
                 return hit[1]
-        slab = np.stack([self._stored_lane_for(st, c) for c in cols])
-        built = self._build_entry(slab)
+        built = self._build_entry(lanes())
         with self._lock:
             entry = self._slabs.get(tk)
-            if entry is None or entry[0]() is not st:
-                ref = weakref.ref(st,
-                                  lambda _, k=tk, d=self._slabs: d.pop(k, None))
+            if entry is None or entry[0]() is not obj:
+                # the weakref callback evicts the entry when the table dies,
+                # so dead tables don't pin their slabs for the engine's
+                # lifetime; it drops only the entry it anchors
+                ref = weakref.ref(
+                    obj, lambda r, k=tk, d=self._slabs: d.pop_anchored(k, r))
                 self._slabs[tk] = (ref, {cols: (n, built)})
             else:
                 cur = entry[1].get(cols)
@@ -1841,7 +1858,10 @@ class PallasBackend(NumpyBackend):
             # set atom m's zone bounds ride in lane rows A..A+M
             rows = rows + list(set_ops.set_cols)
         lo, hi = entry.lo[rows], entry.hi[rows]
-        kw = {}
+        up = {"thr": thr_pad}  # host operands this launch uploads
+        if self.mode == "pallas":
+            up.update(lo=lo, hi=hi)
+        sig = (self.mode, static_atoms, entry.dev.shape, thr_pad.shape)
         if set_ops is not None:
             off, ln = set_ops.off, set_ops.len_
             if Kp != K:
@@ -1853,27 +1873,46 @@ class PallasBackend(NumpyBackend):
             cap = max(1024, 1 << (slab.size - 1).bit_length())
             if cap != slab.size:
                 slab = np.pad(slab, (0, cap - slab.size))
-            kw = dict(set_cols=set_ops.set_cols, set_slab=jnp.asarray(slab),
-                      set_off=jnp.asarray(off), set_len=jnp.asarray(ln))
-        if self.mode == "pallas":
-            out = pred_filter_batch(
-                entry.dev, jnp.asarray(thr_pad), static_atoms,
-                jnp.asarray(lo), jnp.asarray(hi),
-                block_rows=self.block_rows, interpret=self.interpret, **kw)
-        else:
+            up.update(set_slab=slab, set_off=off, set_len=ln)
+            sig += (set_ops.set_cols, cap, set_ops.iters)
+        # a launch that starts before its shape ever finished compiles, or
+        # waits for the thread that does
+        cold = sig not in self._warm
+        with trace.span("launch", K=K, N=entry.n):
+            with trace.span("launch.upload"):
+                dev = {k: jnp.asarray(v) for k, v in up.items()}
+            kw = {}
             if set_ops is not None:
-                kw["iters"] = set_ops.iters
-            out = pred_filter_batch_xla(entry.dev, jnp.asarray(thr_pad),
-                                        static_atoms, **kw)
-        mask = np.asarray(out)[:K, :entry.n]
-        if mask.dtype != np.bool_:
-            mask = mask != 0
+                kw = dict(set_cols=set_ops.set_cols, set_slab=dev["set_slab"],
+                          set_off=dev["set_off"], set_len=dev["set_len"])
+            with trace.span("launch.call"):
+                if self.mode == "pallas":
+                    out = pred_filter_batch(
+                        entry.dev, dev["thr"], static_atoms, dev["lo"],
+                        dev["hi"], block_rows=self.block_rows,
+                        interpret=self.interpret, **kw)
+                else:
+                    if set_ops is not None:
+                        kw["iters"] = set_ops.iters
+                    out = pred_filter_batch_xla(entry.dev, dev["thr"],
+                                                static_atoms, **kw)
+            with trace.span("launch.readback"):
+                host = np.asarray(out)
+            with trace.span("launch.mask"):
+                mask = host[:K, :entry.n]
+                if mask.dtype != np.bool_:
+                    mask = mask != 0
+        if cold:
+            self._warm.add(sig)
+            self._paid_cold()
         if count_stats and self._stats is not None:
             self._stats.bump(
                 device_scans=1,
                 device_rows=K * entry.n,
                 device_blocks_pruned=_skipped_blocks(static_atoms, lo, hi,
                                                      thr, set_ops=set_ops),
+                h2d_bytes=sum(v.nbytes for v in up.values()),
+                d2h_bytes=host.nbytes,
             )
         return mask
 
@@ -2004,6 +2043,10 @@ class ScanStats:
     device_scans: int = 0
     device_rows: int = 0
     device_blocks_pruned: int = 0
+    # bytes each way across the host-device link: launch operands and slab
+    # uploads to the device, launch outputs back
+    h2d_bytes: int = 0
+    d2h_bytes: int = 0
     # coalesced query_batch launches ([B, A] thresholds, one launch for B
     # bindings) and the bindings they covered
     device_batch_scans: int = 0
@@ -2163,6 +2206,11 @@ class ScanEngine:
         Partitioned tables first run the zone-map pruning pass: partitions
         whose statistics prove no row can match are skipped entirely, and the
         survivors are scanned as contiguous slices."""
+        with trace.span("scan", rows=table.nrows, route="serial"):
+            return self._scan_one(pred, table, binding)
+
+    def _scan_one(self, pred: Expr, table: Table,
+                  binding: Optional[Dict[str, object]]) -> np.ndarray:
         self.stats.bump(scans=1)
         prog = self.compile(pred)
         binding = binding or {}
@@ -2257,6 +2305,7 @@ class ScanEngine:
             f"scan:{getattr(table, 'name', None) or '?'}", cands,
             meta={"rows": int(n), "atoms": int(A), "partitions": int(P),
                   "alive": ns, "rows_alive": int(scanned)})
+        trace.note(prune=ch.route)
         t0 = time.perf_counter()
         if ch.route == "parallel":
             self.record_prune(ns, P - ns)
@@ -2307,25 +2356,28 @@ class ScanEngine:
         batch answered in one vectorized pass (see :meth:`scan_batch_idx`)."""
         from .cost import active_recorder
 
-        record = active_recorder() is not None
-        t0 = time.perf_counter() if record else 0.0
-        masks = self._fused_batch(pred, table, bindings)
-        if masks is not None:
-            self.stats.bump(batch_scans=1, batch_rows=len(bindings))
+        with trace.span("scan", rows=table.nrows * len(bindings),
+                        route="device_batch") as sp:
+            record = active_recorder() is not None
+            t0 = time.perf_counter() if record else 0.0
+            masks = self._fused_batch(pred, table, bindings)
+            if masks is not None:
+                self.stats.bump(batch_scans=1, batch_rows=len(bindings))
+                if record:
+                    self._note_batch(pred, table, bindings, "device_batch",
+                                     time.perf_counter() - t0)
+                return masks
+            sp.set(route="batch_pivot")
+            n = table.nrows
+            out = []
+            for idx in self._scan_batch_idx(pred, table, bindings):
+                m = np.zeros(n, dtype=bool)
+                m[idx] = True
+                out.append(m)
             if record:
-                self._note_batch(pred, table, bindings, "device_batch",
+                self._note_batch(pred, table, bindings, "batch_pivot",
                                  time.perf_counter() - t0)
-            return masks
-        n = table.nrows
-        out = []
-        for idx in self.scan_batch_idx(pred, table, bindings):
-            m = np.zeros(n, dtype=bool)
-            m[idx] = True
-            out.append(m)
-        if record:
-            self._note_batch(pred, table, bindings, "batch_pivot",
-                             time.perf_counter() - t0)
-        return out
+            return out
 
     def _note_batch(self, pred: Expr, table: Table, bindings, route: str,
                     seconds: float) -> None:
@@ -2392,6 +2444,13 @@ class ScanEngine:
         hundred elements, not a table scan.  Atoms that resist vectorization
         (array-valued bindings, param-bearing residuals) run per binding on
         the already-tiny candidate sets."""
+        with trace.span("scan", rows=table.nrows * len(bindings),
+                        route="batch_pivot"):
+            return self._scan_batch_idx(pred, table, bindings)
+
+    def _scan_batch_idx(self, pred: Expr, table: Table,
+                        bindings: Sequence[Dict[str, object]]
+                        ) -> List[np.ndarray]:
         B = len(bindings)
         if B == 0:
             return []
@@ -2449,6 +2508,7 @@ class ScanEngine:
             if fused is not None:
                 masks = fused(prog, table, bindings)
                 if masks is not None:
+                    trace.note(route="device_batch")
                     return [np.flatnonzero(m) for m in masks]
             # no usable equality: one shared pass for the static conjunction
             static_mask = np.ones(n, dtype=bool)

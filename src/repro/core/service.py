@@ -48,6 +48,7 @@ hit/stale rates) plus a latency reservoir with p50/p99 — see
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -55,6 +56,7 @@ from typing import Dict, List, Optional, Tuple, Union
 
 import numpy as np
 
+from . import trace
 from .lineage import LineageAnswer, PredTrace, delta_compatible
 from .scan import LRUCache
 
@@ -83,7 +85,7 @@ class LineageRequest:
     dispatcher fulfilment agree on a single outcome."""
 
     __slots__ = ("pipeline", "row", "deadline", "submitted_at", "cache_key",
-                 "_event", "_lock", "_state", "_answer", "_error")
+                 "traced", "_event", "_lock", "_state", "_answer", "_error")
 
     def __init__(self, pipeline: str, row: RowSpec,
                  deadline: Optional[float]):
@@ -95,6 +97,9 @@ class LineageRequest:
         # the dispatcher; None when submit-time normalization failed (the
         # dispatcher then fails the request uniformly)
         self.cache_key: Optional[Tuple] = None
+        # (request id, perf_counter_ns at enqueue) while tracing is on: the
+        # dispatcher records the request's service.queue span from it
+        self.traced: Optional[Tuple[int, int]] = None
         self._event = threading.Event()
         self._lock = threading.Lock()
         self._state = _PENDING
@@ -331,6 +336,7 @@ class LineageService:
         self._cond = threading.Condition()
         self._queue: deque = deque()
         self._closed = False
+        self._ids = itertools.count(1)  # request and batch ids for spans
         self.stats = ServiceStats()
         self.stats.extra_provider = self._cost_stats
         self.stats.tier_provider = self._tier_stats
@@ -503,6 +509,10 @@ class LineageService:
         """Append under the queue lock, re-checking closed-ness: a close()
         racing past the submit-time check must not strand requests in a
         queue nobody drains."""
+        if trace.enabled():
+            now = time.perf_counter_ns()
+            for r in reqs:
+                r.traced = (next(self._ids), now)
         with self._cond:
             if not self._closed:
                 self._queue.extend(reqs)
@@ -556,19 +566,29 @@ class LineageService:
                 # pays the whole window as latency
                 t0 = time.monotonic()
                 seen = len(self._queue)
-                while (len(self._queue) < self.max_batch
-                       and not self._closed):
-                    remaining = self.window_s - (time.monotonic() - t0)
-                    if remaining <= 0:
-                        break
-                    self._cond.wait(min(self.idle_quantum_s, remaining))
-                    if len(self._queue) == seen:
-                        break  # quiescent: nobody is about to join this batch
-                    seen = len(self._queue)
+                with trace.span("service.coalesce"):
+                    while (len(self._queue) < self.max_batch
+                           and not self._closed):
+                        remaining = self.window_s - (time.monotonic() - t0)
+                        if remaining <= 0:
+                            break
+                        self._cond.wait(min(self.idle_quantum_s, remaining))
+                        if len(self._queue) == seen:
+                            break  # quiescent: nobody is about to join this batch
+                        seen = len(self._queue)
                 batch = list(self._queue)
                 self._queue.clear()
+            bid = None
+            if trace.enabled():
+                bid = next(self._ids)
+                now = time.perf_counter_ns()
+                for r in batch:
+                    if r.traced is not None:
+                        trace.record("service.queue", r.traced[1], now,
+                                     r.traced[0], batch=bid)
             try:
-                self._run_batch(batch)
+                with trace.span("service.batch", bid, requests=len(batch)):
+                    self._run_batch(batch)
             except Exception as e:  # pragma: no cover - defensive backstop
                 for r in batch:
                     if r._fail(e):
@@ -601,21 +621,26 @@ class LineageService:
         # cache pass: serve hits, dedupe the misses by binding so N requests
         # for one lineage question cost one query row
         misses: Dict[Tuple, List[LineageRequest]] = {}
-        for r in reqs:
-            ck = r.cache_key  # computed once at submit time
-            if ck is None:
-                try:
-                    ck = _cache_key(key, pt, r.row)
-                except Exception as e:
-                    if r._fail(e):
-                        self.stats.bump(failed=1)
+        hits = missed = 0
+        with trace.span("service.cache") as sp:
+            for r in reqs:
+                ck = r.cache_key  # computed once at submit time
+                if ck is None:
+                    try:
+                        ck = _cache_key(key, pt, r.row)
+                    except Exception as e:
+                        if r._fail(e):
+                            self.stats.bump(failed=1)
+                        continue
+                ans = self._lookup(pt, ck, gen)
+                if ans is not None:
+                    self._finish(r, ans, cached=True)
+                    hits += 1
                     continue
-            ans = self._lookup(pt, ck, gen)
-            if ans is not None:
-                self._finish(r, ans, cached=True)
-                continue
-            self.stats.bump(cache_misses=1)
-            misses.setdefault(ck, []).append(r)
+                self.stats.bump(cache_misses=1)
+                missed += 1
+                misses.setdefault(ck, []).append(r)
+            sp.set(hits=hits, misses=missed)
         if not misses:
             return
         hook = self._pre_query_hook

@@ -60,6 +60,7 @@ from typing import Dict, Iterator, List, Mapping, Optional, Tuple
 
 import numpy as np
 
+from . import trace
 from .expr import eval_np
 from .scan import (
     EQ, OPS, _NP_CMP, AtomProgram, LRUCache, NumpyBackend, ScanEngine,
@@ -1445,11 +1446,19 @@ class IntermediateStore:
         path — and the cheapest one executes (falling down the ranking when
         a route proves inviable, e.g. the program leaves the encoded-int32
         device fragment)."""
+        with trace.span("scan", rows=self.stages[node_id].nrows) as sp:
+            mask, route = self._scan_stage(node_id, pred, binding or {},
+                                           engine)
+            sp.set(route=route)
+            return mask
+
+    def _scan_stage(self, node_id: int, pred, binding: Dict[str, object],
+                    engine: ScanEngine):
+        """``(mask, route taken)`` of :meth:`scan`."""
         from .cost import prog_atoms
 
         prog = engine.compile(pred)
         st = self.stages[node_id]
-        binding = binding or {}
         cm = engine.cost_model
         n = st.nrows
         A = prog_atoms(prog)
@@ -1465,7 +1474,7 @@ class IntermediateStore:
             if ns == 0:
                 engine.stats.bump(scans=1, insitu_scans=1, prune_calls=1)
                 engine.record_prune(0, P)
-                return np.zeros(n, dtype=bool)
+                return np.zeros(n, dtype=bool), "pruned"
             kept = n - int(zm.part_sizes()[~alive].sum())
             # candidate-mode gather pays per-row index work plus up to one
             # partition of slack; the PRUNED_RATIO seed reproduces the old
@@ -1520,6 +1529,8 @@ class IntermediateStore:
         ch = cm.choose(f"store:{node_id}", cands, meta=meta)
         executed = None
         mask = None
+        colds = getattr(engine.backend, "colds", lambda: 0)
+        c0 = colds()
         t0 = time.perf_counter()
         for _, route, _ in ch.ranked:
             if route == "pruned":
@@ -1556,8 +1567,10 @@ class IntermediateStore:
                 engine.stats.bump(scans=1, insitu_scans=1, insitu_chosen=1)
             executed = route
             break
-        ch.done(time.perf_counter() - t0, route=executed)
-        return mask
+        # a first launch compiles or uploads: not a steady-state timing
+        ch.done(time.perf_counter() - t0, route=executed,
+                observe=colds() == c0)
+        return mask, executed
 
     @staticmethod
     def _note_unpruned(engine: ScanEngine, alive, P: int) -> None:

@@ -134,6 +134,7 @@ def pred_filter(
         ],
         out_specs=pl.BlockSpec((block_rows,), lambda i: (i,)),
         interpret=interpret,
+        name="pred_filter",
     )(cols, thresholds)
 
 
@@ -353,6 +354,8 @@ def pred_filter_batch(
         out_specs=pl.BlockSpec((K, block_rows), lambda i: (0, i)),
         scratch_shapes=scratch,
         interpret=interpret,
+        # a stable kernel name: the op a device trace shows
+        name="pred_filter_batch",
     )(*operands)
 
 

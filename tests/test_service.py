@@ -335,3 +335,35 @@ def test_service_stats_shape(pipelines):
               "latency_ms_p99", "expired", "cancelled", "failed"):
         assert k in st, k
     svc.close()
+
+
+def test_one_traced_batch_gives_a_queue_span_per_request(pipelines):
+    """Four distinct rows sent in one ``submit_many`` coalesce into one
+    batch: one ``service.queue`` span per request, each carrying the batch's
+    id, and one root ``lineage.query_batch`` under the batch's span."""
+    from repro.core import trace
+
+    svc = LineageService({"q3": pipelines["q3"]}, max_batch=8, window_s=0.05)
+    trace.start()
+    try:
+        for r in svc.submit_many([0, 1, 2, 3], "q3", timeout=JOIN_TIMEOUT):
+            r.result(JOIN_TIMEOUT)
+    finally:
+        spans = trace.stop()
+        svc.close()
+    by_id = {s.id: s for s in spans}
+    batch, = [s for s in spans if s.name == "service.batch"]
+    assert batch.attrs == {"requests": 4}
+    queue = [s for s in spans if s.name == "service.queue"]
+    assert len(queue) == 4 and len({s.req for s in queue}) == 4
+    assert all(s.attrs == {"batch": batch.req} for s in queue)
+    assert all(s.end_ns <= batch.start_ns for s in queue)
+    cache, = [s for s in spans if s.name == "service.cache"]
+    assert cache.attrs == {"hits": 0, "misses": 4}
+    roots = [s for s in spans if s.name.startswith("lineage.")
+             and not by_id[s.parent].name.startswith("lineage.")]
+    assert [s.name for s in roots] == ["lineage.query_batch"]
+    assert roots[0].attrs == {"rows": 4}
+    assert (roots[0].parent, roots[0].req) == (batch.id, batch.req)
+    names = {s.name for s in spans}
+    assert {"lineage.bind", "lineage.stage", "lineage.source", "scan"} <= names

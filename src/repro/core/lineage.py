@@ -89,10 +89,10 @@ def _binding_groups(pred: Expr, binding: Dict[str, object],
 
 
 def _zone_restrict(table: Table, atoms) -> np.ndarray:
-    """Candidate row indices for the tuple-membership evaluator: on a
-    partitioned table, partitions whose zone-map range cannot intersect the
-    leading atom's value set are dropped before the full-column ``isin`` —
-    the same conservative pruning the ScanEngine applies to plain scans."""
+    """Candidate row indices for the whole-column tuple-membership path: on
+    a partitioned table, partitions whose zone-map range cannot intersect
+    the leading atom's value set are dropped before the full-column ``isin``
+    — the same conservative pruning the ScanEngine applies to plain scans."""
     from .scan import _set_overlap
     from .table import PartitionedTable, rows_of_alive
 
@@ -109,10 +109,57 @@ def _zone_restrict(table: Table, atoms) -> np.ndarray:
     return np.arange(n)
 
 
+def _tuple_members(table: Table, atoms, engine: Optional[ScanEngine] = None):
+    """``(route, idx)``: sorted row indices of ``table`` whose left-side
+    values form a tuple of the stage selection, one ``(lhs, stage values)``
+    atom per tuple column.
+
+    With an ``engine`` and a plain-column leading atom, the leading atom's
+    candidates come from the engine's cached sorted-column index (route
+    ``index``); otherwise from a whole-column ``isin`` after zone pruning
+    (route ``isin``), which suits tables seen once, such as fresh delta
+    views."""
+    if engine is not None and atoms and isinstance(atoms[0][0], Col):
+        col = atoms[0][0].name
+        idx = engine.member_batch_idx(table, atoms[0][0], [atoms[0][1]])[0]
+        route, idx = "index", _tuple_narrow(table, idx, atoms,
+                                            [table.cols[col][idx]])
+    else:
+        route = "isin"
+        idx = _tuple_narrow(table, _zone_restrict(table, atoms), atoms, [])
+    if engine is not None:
+        engine.stats.bump(**{f"tuple_{route}_groups": 1})
+    return route, idx
+
+
+def _tuple_narrow(table: Table, idx: np.ndarray, atoms, vals) -> np.ndarray:
+    """Candidate rows ``idx`` narrowed to exact tuple membership.  ``vals``
+    holds the left-side values at ``idx`` of the leading atoms already
+    applied; each later atom narrows by ``isin``, then the composite codes
+    of all atoms check the tuples — independent per-atom value sets would be
+    a cross-product superset."""
+    from .executor import composite_codes
+
+    for lhs, sel_vals in atoms[len(vals):]:
+        if not len(idx):
+            break
+        env = {c: table.cols[c][idx] for c in cols_of(lhs)}
+        v = np.asarray(eval_np(lhs, env, {}, n=len(idx)))
+        keep = np.isin(v, sel_vals)
+        idx = idx[keep]
+        vals = [lv[keep] for lv in vals]
+        vals.append(v[keep])
+    if len(atoms) > 1 and len(idx):
+        ct, cs = composite_codes(vals, [sv for _, sv in atoms])
+        idx = idx[np.isin(ct, cs)]
+    return idx
+
+
 def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
                param_stage: Dict[str, int], stage_sel: Dict[int, Table],
                param_col: Dict[str, str],
-               scan=None, analysis=None) -> np.ndarray:
+               scan=None, analysis=None,
+               engine: Optional[ScanEngine] = None) -> np.ndarray:
     """Evaluate a concretized predicate.
 
     Array-bound params appearing only in equality atoms keep set semantics
@@ -122,7 +169,9 @@ def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
     the corresponding rows".  ``scan`` is the compiled-scan backend for the
     plain-conjunction fragments (defaults to the tree evaluator);
     ``analysis`` the binding-independent pair :func:`_binding_groups`
-    accepts, for callers that evaluate one predicate many times."""
+    accepts, for callers that evaluate one predicate many times; ``engine``
+    the owner of the sorted-column indexes the tuple groups probe
+    (:func:`_tuple_members`)."""
     if scan is None:
         scan = lambda p, t, b: np.asarray(eval_np(p, t.cols, b, n=t.nrows), bool)
     tuple_groups, rowwise = _binding_groups(pred, binding, param_stage,
@@ -130,21 +179,11 @@ def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
     if not rowwise and not tuple_groups:
         return scan(pred, table, binding)
 
-    mask = np.ones(table.nrows, dtype=bool)
     consumed_atoms = []
-
-    # composite-tuple membership: exact — independent per-atom value sets
-    # would be a cross-product superset.  Evaluation narrows progressively
-    # (first atoms are usually keys), then verifies tuple consistency on the
-    # few surviving candidates.
-    from .expr import cols_of as _cols_of
-    from .scan import _sorted_unique
-
+    cand = None  # row indices every tuple group admits; None: all rows
     for sid, plist in tuple_groups.items():
-        from .executor import composite_codes
-
         sel = stage_sel[sid]
-        with trace.span("lineage.tuple", rows=sel.nrows):
+        with trace.span("lineage.tuple", rows=sel.nrows) as ts:
             atoms = []
             for a in conjuncts(pred):
                 ap = params_of(a)
@@ -153,24 +192,15 @@ def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
                     lhs = a.left if isinstance(a.right, Param) else a.right
                     atoms.append((lhs, np.asarray(sel.cols[param_col[p]])))
                     consumed_atoms.append(a)
-            idx = _zone_restrict(table, atoms)
-            lhs_vals = []
-            for lhs, sel_vals in atoms:
-                env = {c: table.cols[c][idx] for c in _cols_of(lhs)}
-                v = np.asarray(eval_np(lhs, env, {}, n=len(idx)))
-                # sorted-unique is hoisted out of the per-partition loop: the
-                # stage selection array is the same object every call, so the
-                # id-keyed cache sorts it once per predicate, not once per part
-                keep = np.isin(v, _sorted_unique(sel_vals))
-                idx = idx[keep]
-                lhs_vals = [lv[keep] for lv in lhs_vals]
-                lhs_vals.append(v[keep])
-            if len(atoms) > 1 and len(idx):
-                ct, cs = composite_codes(lhs_vals, [sv for _, sv in atoms])
-                idx = idx[np.isin(ct, cs)]
-            gmask = np.zeros(table.nrows, dtype=bool)
-            gmask[idx] = True
-            mask &= gmask
+            route, idx = _tuple_members(table, atoms, engine)
+            cand = idx if cand is None else np.intersect1d(
+                cand, idx, assume_unique=True)
+            ts.set(route=route)
+    if cand is None:
+        mask = np.ones(table.nrows, dtype=bool)
+    else:
+        mask = np.zeros(table.nrows, dtype=bool)
+        mask[cand] = True
 
     rest = [a for a in conjuncts(pred) if a not in consumed_atoms]
     rest_params = set()
@@ -738,7 +768,8 @@ class PredTrace:
             else:
                 table, route = stobj, "plain"
             m = _eval_pred(st.run_pred, table, binding, param_stage,
-                           stage_sel, param_col, scan=self._scan)
+                           stage_sel, param_col, scan=self._scan,
+                           engine=self.scan_engine)
             sel = table.mask(m)
             sp.set(route=route, rows=sel.nrows)
             return sel
@@ -822,7 +853,8 @@ class PredTrace:
                     rids = np.array([], dtype=np.int64)
                 else:
                     m = _eval_pred(sp.pred, t, binding, param_stage, stage_sel,
-                                   param_col, scan=scan)
+                                   param_col, scan=scan,
+                                   engine=self.scan_engine)
                     rids = t.rids()[m]
                 lineage[sp.table] = (
                     np.union1d(lineage[sp.table], rids)
@@ -1009,18 +1041,20 @@ class PredTrace:
                 meta={"table": sp.table, "delta_rows": int(view.nrows),
                       "total_rows": int(t.nrows)},
             )
-            scan_t = t if choice.route == "serial" else view
+            serial = choice.route == "serial"
+            scan_t = t if serial else view
             t1 = time.perf_counter()
-            # delta views are small; the engine's partition planning and
-            # pruning would cost more than the scan itself, so the rescan
-            # route uses the tree evaluator directly
+            # delta views are small and fresh; the engine's partition
+            # planning, pruning and sorted indexes would cost more than the
+            # scan itself, so the rescan route uses the tree evaluator and
+            # whole-column tuple membership directly
             m = _eval_pred(sp.pred, scan_t, binding, param_stage, stage_sel,
-                           param_col,
-                           scan=self._scan if choice.route == "serial"
-                           else None, analysis=sp_pair)
+                           param_col, scan=self._scan if serial else None,
+                           analysis=sp_pair,
+                           engine=self.scan_engine if serial else None)
             rids = scan_t.rids()[m]
             choice.done(time.perf_counter() - t1)
-            if choice.route == "serial":
+            if serial:
                 scanned = total_parts
             elif alive is not None:
                 scanned = int(alive.sum())
@@ -1266,24 +1300,12 @@ class PredTrace:
                     table, lhs0, [sel_col(b, sid, p0) for b in bs]
                 )
                 for j, b in enumerate(bs):
-                    idx = cand0[j]
-                    vals = [lhs_vals(lhs0, idx)]
-                    for lhs, p in atoms[1:]:
-                        if not len(idx):
-                            break
-                        v = lhs_vals(lhs, idx)
-                        keep = np.isin(v, np.unique(sel_col(b, sid, p)))
-                        idx = idx[keep]
-                        vals = [lv[keep] for lv in vals]
-                        vals.append(v[keep])
-                    if len(atoms) > 1 and len(idx):
-                        from .executor import composite_codes
-
-                        ct, cs = composite_codes(
-                            vals, [np.asarray(sel_col(b, sid, p)) for _, p in atoms]
-                        )
-                        idx = idx[np.isin(ct, cs)]
+                    idx = _tuple_narrow(
+                        table, cand0[j],
+                        [(lhs, sel_col(b, sid, p)) for lhs, p in atoms],
+                        [lhs_vals(lhs0, cand0[j])])
                     out[b] = idx if b not in out else np.intersect1d(out[b], idx)
+                self.scan_engine.stats.bump(tuple_index_groups=len(bs))
             if rest_pred is not None:
                 for b in bs:
                     idx = out[b]
@@ -1323,8 +1345,10 @@ class PredTrace:
             for entries in tuple_groups.values():
                 with trace.span("lineage.tuple", rows=sum(
                         len(stage_idxs[b].get(sid, empty))
-                        for b, tg in entries for sid in tg)):
+                        for b, tg in entries for sid in tg)) as ts:
                     res = tuple_batch(pred, table, entries)
+                    if res is not None:
+                        ts.set(route="index")
                 if res is None:
                     per_row.extend(b for b, _ in entries)
                 else:
@@ -1332,7 +1356,8 @@ class PredTrace:
                         idxs[b] = idx
             for b in per_row:
                 m = _eval_pred(pred, table, bindings[b], param_stage,
-                               stage_sels(b), param_col, scan=scan)
+                               stage_sels(b), param_col, scan=scan,
+                               engine=self.scan_engine)
                 idxs[b] = np.nonzero(m)[0]
             if simple:
                 batched = self.scan_engine.scan_batch_idx(

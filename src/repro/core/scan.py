@@ -203,9 +203,8 @@ class LRUCache:
                     "evictions": self.evictions}
 
 
-# membership-set sort cache: zone-restrict overlap checks and the tuple-
-# membership evaluator consult the same value sets once per partition /
-# per atom; the sort+unique is hoisted here.  Entries anchor the keyed
+# membership-set sort cache: zone-map overlap checks consult the same value
+# sets once per predicate and scan; the sort+unique is hoisted here.  Entries anchor the keyed
 # array with a weakref whose callback evicts on collection, so a recycled
 # id() can never find a stale entry; values that reject weakrefs (lists,
 # frozensets) are anchored by strong ref, which pins their id for the
@@ -232,6 +231,16 @@ def _sorted_unique(vals: np.ndarray) -> np.ndarray:
         anchor = vals
     _SORTED_SETS[k] = (anchor, u)
     return u
+
+
+def _in_dtype_of(u: np.ndarray, dt: np.dtype) -> np.ndarray:
+    """Integer probe values cast to an integer column's dtype, those outside
+    its range dropped (no row can equal them): ``np.searchsorted`` would
+    otherwise cast the whole sorted column to the common dtype per call."""
+    if u.dtype == dt or u.dtype.kind not in "iu" or dt.kind not in "iu":
+        return u
+    info = np.iinfo(dt)
+    return u[(u >= info.min) & (u <= info.max)].astype(dt)
 
 
 def sorted_set_counters() -> Dict[str, int]:
@@ -2080,6 +2089,10 @@ class ScanStats:
     # scans the worker pool actually fanned out (surviving work cleared the
     # measured cutover); zero means the parallel path ran serial throughout
     fanout_scans: int = 0
+    # tuple-membership groups of a lineage walk (core/lineage.py): leading
+    # atom answered from the sorted-column index, or by a whole-column isin
+    tuple_index_groups: int = 0
+    tuple_isin_groups: int = 0
     # the engine's bounded caches, registered for the stats() snapshot
     caches: Dict[str, "LRUCache"] = field(default_factory=dict, repr=False)
     # counter increments are read-modify-write; concurrent scans (the
@@ -2608,6 +2621,7 @@ class ScanEngine:
             u = np.unique(np.asarray(vals))
             if u.dtype.kind == "f":
                 u = u[~np.isnan(u)]  # searchsorted would pair NaN with NaN
+            u = _in_dtype_of(u, sorted_vals.dtype)
             lo = np.searchsorted(sorted_vals, u, side="left")
             hi = np.searchsorted(sorted_vals, u, side="right")
             segs = [order[l:h] for l, h in zip(lo, hi) if h > l]

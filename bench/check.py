@@ -19,16 +19,16 @@ fails the run.
 
 The question a request asks is an output row index of the program's
 pipeline; it is read back as that row's group-key values, and the
-reference answers for the group with those values.
+reference (the configuration's, ``registry.reference``) answers for the
+group with those values.
 """
 
 from __future__ import annotations
 
+from types import ModuleType
 from typing import Dict, List, Tuple
 
 import numpy as np
-
-from .reference import tpch_lineage as ref
 
 LIMITS = {"missing": 0, "wrong_output_rows": 0, "unknown_row": 0,
           "wrong_lineage": 0, "not_precise": 0, "source_changed": 0}
@@ -41,12 +41,14 @@ def same_rows(got, want: np.ndarray) -> bool:
     return np.array_equal(np.unique(g.astype(np.int64)), want)
 
 
-def compare(data, outputs: Dict[str, Dict[str, np.ndarray]],
+def compare(ref: ModuleType, data, outputs: Dict[str, List[Tuple]],
             sample: List[Tuple[str, int, object]], missing: int,
             source_changed: int = 0) -> Dict:
-    """``data`` is the source copy (``{table: (cols, dicts)}``);
-    ``outputs[q]`` holds the program's output group columns of pipeline
-    ``q``; ``sample`` is ``(pipeline, row, LineageAnswer)`` triples."""
+    """``ref`` is the reference module (``build(data, q)``); ``data`` the
+    source copy (``{table: (cols, dicts)}``); ``outputs[q]`` the group-key
+    tuple of each output row of the program's pipeline ``q`` (``()`` for
+    each row of a global aggregate); ``sample`` is ``(pipeline, row,
+    LineageAnswer)`` triples."""
     counts = dict.fromkeys(LIMITS, 0)
     counts["missing"] = missing
     counts["source_changed"] = source_changed
@@ -54,10 +56,9 @@ def compare(data, outputs: Dict[str, Dict[str, np.ndarray]],
     refs = {}
     for q in sorted({q for q, _, _ in sample}):
         refs[q] = ref.build(data, q)
-        n_out = len(next(iter(outputs[q].values())))
-        counts["wrong_output_rows"] += int(n_out != len(refs[q]))
+        counts["wrong_output_rows"] += int(len(outputs[q]) != len(refs[q]))
     for q, row, ans in sample:
-        key = tuple(outputs[q][c][row].item() for c in ref.GROUP_KEYS[q])
+        key = outputs[q][row]
         if key not in refs[q]:
             counts["unknown_row"] += 1
             continue

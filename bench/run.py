@@ -11,7 +11,8 @@ by asking its rows once.  The window then drives
 ``LineageService.submit_many`` with the cell's traffic mix
 (``bench/traffic/<name>.json``, read by ``bench/traffic.py``) for
 ``--seconds``, timed from the client side.  Afterwards every sampled answer
-is compared with the plain reference (``bench/reference``); see
+is compared with the configuration's plain reference
+(``bench/reference/<stem>.py``, ``registry.reference``); see
 ``bench/check.py``.
 
 The metrics are the cell's end-to-end ones, or with ``--trace 1`` its
@@ -189,11 +190,16 @@ class Deployment:
                     total[k] = total.get(k, 0) + v
         return total
 
-    def outputs(self, queries):
-        from bench.reference.tpch_lineage import GROUP_KEYS
-
-        return {q: {c: np.asarray(self.pts[q].exec_result.output.cols[c])
-                    for c in GROUP_KEYS[q]} for q in queries}
+    def outputs(self, queries, group_keys):
+        """The group-key tuple of every output row of each pipeline, by the
+        reference's ``GROUP_KEYS``."""
+        out = {}
+        for q in queries:
+            cols = self.pts[q].exec_result.output.cols
+            keys = [np.asarray(cols[c]).tolist() for c in group_keys[q]]
+            out[q] = (list(zip(*keys)) if keys
+                      else [()] * self.out_rows[q])
+        return out
 
     def source_changed(self) -> int:
         """Source columns and vocabularies that no longer equal the copy
@@ -526,6 +532,7 @@ def run(argv=None):
     sp = registry.spec()
     cell = registry.workload(sp, args.workload)
     cfg = registry.config(sp, cell["config"])
+    ref = registry.reference(cfg)  # before any data: it must know each pipeline
     mix = registry.traffic(cell["traffic"])
     devs = devices(int(cell["chips"]), args.rehearse)
     import jax
@@ -584,7 +591,7 @@ def run(argv=None):
         f"{json.dumps(facts)}")
 
     qs = sorted({q for q, _, _ in col.sample})
-    outputs = dep.outputs(qs)
+    outputs = dep.outputs(qs, ref.GROUP_KEYS)
     launch_bytes = None
     records = None
     if tdir is not None:
@@ -598,11 +605,15 @@ def run(argv=None):
     t_ref = time.perf_counter()
     changed = dep.source_changed()
     dep.close()
-    checks = check.compare(dep.source, outputs, col.sample, failed, changed)
+    checks = check.compare(ref, dep.source, outputs, col.sample, failed,
+                           changed)
     ref_s = time.perf_counter() - t_ref
     correct = check.passed(checks)
+    held = sum(np.asarray(v).nbytes for _, _, ans in col.sample
+               for v in ans.lineage.values())
     log(f"compared {len(col.sample)} answers of {col.seen} in "
-        f"{ref_s:.3f} s (reference and comparison)")
+        f"{ref_s:.3f} s (reference and comparison); the sampled answers' "
+        f"row ids hold {held} bytes of host memory")
 
     platform = devs[0].platform
     device = {"platform": platform, "kind": devs[0].device_kind,

@@ -84,6 +84,51 @@ def test_registry_looks_pieces_up_by_file_name(tmp_path):
         registry.workload(sp, "w2")
 
 
+def test_a_configuration_without_a_reference_key_uses_tpch_lineage():
+    ref = registry.reference({"pipelines": ["q3", "q21"]})
+    assert ref.__file__ == str(registry.BENCH / "reference" / "tpch_lineage.py")
+    assert set(ref.GROUP_KEYS) >= {"q3", "q21"} and callable(ref.build)
+    sp = registry.spec()
+    for cell in sp["workloads"]:
+        cfg = registry.config(sp, cell["config"])
+        assert set(cfg["pipelines"]) <= set(registry.reference(cfg).GROUP_KEYS)
+
+
+def test_a_named_reference_resolves_to_its_file_and_an_unknown_one_raises(
+        tmp_path):
+    (tmp_path / "reference").mkdir()
+    (tmp_path / "reference" / "mine.py").write_text(
+        "GROUP_KEYS = {'p1': ('k',)}\n"
+        "def build(data, query):\n    return query\n")
+    ref = registry.reference({"reference": "mine", "pipelines": ["p1"]},
+                             bench=tmp_path)
+    assert ref.GROUP_KEYS == {"p1": ("k",)} and ref.build(None, "p1") == "p1"
+    with pytest.raises(FileNotFoundError, match=str(tmp_path / "reference")):
+        registry.reference({"reference": "absent"}, bench=tmp_path)
+    with pytest.raises(KeyError, match="'mine'.*'p2'"):
+        registry.reference({"reference": "mine", "pipelines": ["p1", "p2"]},
+                           bench=tmp_path)
+
+
+def test_an_unknown_pipeline_fails_setup_before_any_data(monkeypatch):
+    import repro.tpch
+
+    from bench import run
+
+    sp = registry.spec()
+    cfg = dict(registry.config(sp, sp["workloads"][0]["config"]),
+               reference="tpch_reports")
+    monkeypatch.setattr(registry, "config", lambda *a, **kw: cfg)
+
+    def generate(*a, **kw):
+        raise AssertionError("data generated before the reference was checked")
+
+    monkeypatch.setattr(repro.tpch, "generate", generate)
+    with pytest.raises(KeyError, match="'tpch_reports'.*'q3'"):
+        run.run(["--workload", sp["workloads"][0]["name"], "--seed", "1",
+                 "--seconds", "1", "--rehearse", "--sf", "0.002"])
+
+
 def test_open_schedule_gives_every_seed_the_same_sessions_in_another_order():
     mix = registry.traffic("debug")
     rows = {"a": 50, "b": 7, "c": 1000}

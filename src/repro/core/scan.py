@@ -47,7 +47,8 @@ import time
 import weakref
 from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from types import SimpleNamespace
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -838,6 +839,30 @@ def _prep_set_raw(arr: np.ndarray, flavor: str) -> Optional[np.ndarray]:
     return np.unique(_f32_key(f32[keep]))
 
 
+class _DecimalLane(NamedTuple):
+    """A float64 column held on the chip as the int32 ``k`` of its values
+    ``k / scale``: the shape of the store's ``scaled`` encoding over a plain
+    inner lane, so ``PallasBackend._scaled_thr`` translates its thresholds."""
+
+    scale: int
+    dtype: np.dtype = np.dtype(np.float64)
+    inner: object = SimpleNamespace(kind="plain")
+
+
+def _lane_order(lanes) -> Tuple:
+    """Slab row order: column names first, then derived lanes (tuples)."""
+    return tuple(sorted(lanes, key=lambda c: (isinstance(c, tuple), c)))
+
+
+# ScanStats counter bumped by a launch that carries a lane of each flavor
+_LANE_COUNTERS = {"f32": "float_lane_scans", "pair": "pair_lane_scans",
+                  "dec": "decimal_lane_scans"}
+
+
+def _lane_bumps(flavors) -> Dict[str, int]:
+    return {_LANE_COUNTERS[f]: 1 for f in set(flavors) if f in _LANE_COUNTERS}
+
+
 class _KernelSlab:
     """Device-resident launch operands for one (table, column-set): the
     padded int32 slab uploaded once, plus per-block min/max bounds the
@@ -855,17 +880,27 @@ class _KernelSlab:
 class PallasBackend(NumpyBackend):
     """Device carrier for predicate scans.
 
-    Comparison atoms in the int32 fragment run through the fused
-    ``kernels/pred_filter`` batched kernel over a device-resident columnar
-    slab (uploaded once per table/column-set, with per-block zone bounds
-    fused into the launch).  float32 comparisons join the same launch via
-    an order-preserving sign-folded int32 key lane with thresholds
-    translated exactly (NaN / ±inf / -0.0 semantics match numpy
-    bit-for-bit), and ``IN`` atoms evaluate *in-grid* against sorted set
-    segments cached on device next to the slab — one launch carries the
-    whole atom program.  Atoms outside the fragment
-    (float64 columns, unbound params, residuals) fall back to the NumPy
-    oracle — correctness never depends on the kernel fragment.
+    The kernel compares int32 lanes of a device-resident slab with int32
+    thresholds (``(lane, op)`` atoms, per-block zone bounds fused into the
+    launch; a slab is uploaded once per table and lane set).  A lane is one
+    of four flavors, each exact against numpy:
+
+    * ``int``: an int32-exact column as it is;
+    * ``f32``: a float32 column as order-preserving sign-folded keys
+      (NaN / ±inf / -0.0 semantics match numpy bit for bit);
+    * ``dec``: a float64 column whose values are all ``k / scale`` for
+      int32 ``k`` (the store's ``scaled`` fit test), held as ``k``, with
+      thresholds moved onto ``k`` by the verified walk ``_scaled_thr``;
+    * ``pair``: the difference ``a - b`` of two int32-exact columns when it
+      fits int32, which carries the column-pair atom ``a <op> b`` as
+      ``(a - b) <op> 0``.
+
+    ``IN`` atoms evaluate *in-grid* against sorted set segments cached on
+    device next to the slab, so one launch carries the whole atom program.
+    Atoms outside the fragment (a float64 column that is not decimal, wide
+    integers, array-bound params, unbound params, residuals) fall back to
+    the NumPy oracle, counted as ``ScanStats.host_atoms`` — correctness
+    never depends on the kernel fragment.
 
     ``interpret=None`` (default) resolves the execution mode per host:
     compiled Pallas on TPU, the jitted XLA graph of the same fused
@@ -1135,10 +1170,11 @@ class PallasBackend(NumpyBackend):
              binding: Dict[str, object]) -> np.ndarray:
         n = table.nrows
         mask = np.ones(n, dtype=bool)
-        kernel_cmp, fallback_cmp = self._split_cmp(prog, table, binding)
+        why: Dict[str, int] = {}  # host atoms by the reason they stayed
+        kernel_cmp, fallback_cmp = self._split_cmp(prog, table, binding, why)
         if n:
             kernel_isin, fallback_isin = self._split_isin(prog, table,
-                                                          binding)
+                                                          binding, why)
         else:
             kernel_isin, fallback_isin = [], list(prog.isin_atoms)
         ch = None
@@ -1148,7 +1184,7 @@ class PallasBackend(NumpyBackend):
             # key lane otherwise, plain int32 compares else
             route = ("device_member" if kernel_isin
                      else "device_float" if any(
-                         self._f32_col(table, a.col) for a in kernel_cmp)
+                         k[2] == "f32" for k in kernel_cmp)
                      else "device")
             seed = (self._member_seed() if route == "device_member"
                     else self._device_seed())
@@ -1174,24 +1210,33 @@ class PallasBackend(NumpyBackend):
                     n, len(kernel_cmp) + len(kernel_isin), 1)
             if not use_dev:
                 # below the measured crossover the numpy path wins — keep it
-                fallback_cmp = kernel_cmp + fallback_cmp
+                why["cutover"] = len(kernel_cmp) + len(kernel_isin)
+                fallback_cmp = [k[0] for k in kernel_cmp] + fallback_cmp
                 kernel_cmp = []
                 fallback_isin = [a for a, _ in kernel_isin] + fallback_isin
                 kernel_isin = []
         dev = bool((kernel_cmp or kernel_isin) and n)
-        trace.note(route=route if dev else "serial")
+        n_dev = len(kernel_cmp) + len(kernel_isin) if dev else 0
+        n_host = len(prog.cmp_atoms) + len(prog.isin_atoms) - n_dev
+        trace.note(route=route if dev else "serial", kernel_atoms=n_dev,
+                   host_atoms=n_host, host_reasons=why)
+        if self._stats is not None:
+            self._stats.bump(kernel_atoms=n_dev, host_atoms=n_host)
         colds = self.colds()
         t0 = time.perf_counter() if ch is not None else 0.0
         if dev:
-            mask &= self._kernel_scan(kernel_cmp, table, binding,
-                                      isin=kernel_isin)
-        for a in fallback_cmp:
-            mask &= self._cmp_mask(a, table, binding, n)
-        for a in fallback_isin:
-            mask &= self._isin_mask(a, table, binding, n)
-        for r in (prog.residual_static, prog.residual_dynamic):
-            if r is not None:
-                mask &= np.asarray(eval_np(r, table.cols, binding, n=n), bool)
+            mask &= self._kernel_scan(kernel_cmp, table, isin=kernel_isin)
+        residuals = [r for r in (prog.residual_static, prog.residual_dynamic)
+                     if r is not None]
+        if fallback_cmp or fallback_isin or residuals:
+            with trace.span("scan.host", atoms=n_host, rows=n):
+                for a in fallback_cmp:
+                    mask &= self._cmp_mask(a, table, binding, n)
+                for a in fallback_isin:
+                    mask &= self._isin_mask(a, table, binding, n)
+                for r in residuals:
+                    mask &= np.asarray(eval_np(r, table.cols, binding, n=n),
+                                       bool)
         if ch is not None:
             ch.done(time.perf_counter() - t0, observe=self.colds() == colds)
         return mask
@@ -1204,10 +1249,12 @@ class PallasBackend(NumpyBackend):
         column block is read once for all B predicates, and in-grid zone
         pruning skips blocks no binding can match.  Membership atoms ride
         the same launch as ragged per-binding set segments; float32 atoms
-        expand into key-space intervals with op-only static structure.
-        Returns None when the program leaves the kernel fragment or the
-        batch is below the measured cutover (callers keep the host batch
-        path)."""
+        expand into key-space intervals with op-only static structure;
+        decimal and column-pair atoms ride their lanes (``_lane_atoms``).
+        Returns None when the program leaves the kernel fragment, when the
+        bindings' thresholds do not share one static atom structure, or
+        when the batch is below the measured cutover (callers keep the host
+        batch path)."""
         if (prog.residual_static is not None
                 or prog.residual_dynamic is not None
                 or not (prog.cmp_atoms or prog.isin_atoms) or not bindings):
@@ -1218,46 +1265,36 @@ class PallasBackend(NumpyBackend):
                                       len(bindings)):
             return None
         B = len(bindings)
-        cols = tuple(sorted({a.col for a in atoms}
-                            | {a.col for a in prog.isin_atoms}))
+        # per atom: its lane, flavor, and the (op, threshold) pairs of each
+        # binding, whose ops must agree (one static structure for the batch)
+        lanes = []
+        for a in atoms:
+            if a.kind == "param":
+                per = []
+                for b in bindings:
+                    la = self._lane_atoms(table, a, _bind(b, a.rhs))
+                    if la is None:
+                        return None
+                    per.append(la)
+            else:
+                la = self._lane_atoms(table, a, a.rhs)
+                if la is None:
+                    return None
+                per = [la] * B
+            ops = [op for op, _ in per[0][2]]
+            if any([op for op, _ in p[2]] != ops for p in per):
+                return None
+            lanes.append((per[0][0], per[0][1], ops, per))
+        cols = _lane_order({lane for lane, _, _, _ in lanes}
+                           | {a.col for a in prog.isin_atoms})
         order = {c: i for i, c in enumerate(cols)}
         static: List[Tuple[int, int]] = []
         thr_cols: List[np.ndarray] = []
-        for a in atoms:
-            if a.kind == "col":
-                return None
-            flavor = self._col_flavor(table, a.col)
-            if flavor is None:
-                return None
-            if flavor == "f32":
-                # canonical expansions share static structure across B:
-                # one key atom for ==/!=, a two-sided interval otherwise
-                plans = []
-                for b in bindings:
-                    v = a.rhs if a.kind == "lit" else _bind(b, a.rhs)
-                    p = _f32_atoms(a.op, v)
-                    if p is None:
-                        return None
-                    plans.append(p)
-                for j in range(len(plans[0])):
-                    static.append((order[a.col], plans[0][j][0]))
-                    thr_cols.append(np.asarray([p[j][1] for p in plans],
-                                               dtype=np.int32))
-                continue
-            if a.kind == "lit":
-                t = self._kernel_value(a.rhs)
-                if t is None:
-                    return None
-                col_thr = np.full(B, t, dtype=np.int32)
-            else:
-                col_thr = np.empty(B, dtype=np.int32)
-                for k, b in enumerate(bindings):
-                    t = self._kernel_value(_bind(b, a.rhs))
-                    if t is None:
-                        return None
-                    col_thr[k] = t
-            static.append((order[a.col], a.op))
-            thr_cols.append(col_thr)
+        for lane, _, ops, per in lanes:
+            for j, op in enumerate(ops):
+                static.append((order[lane], op))
+                thr_cols.append(np.asarray([p[2][j][1] for p in per],
+                                           dtype=np.int32))
         set_ops = None
         if prog.isin_atoms:
             set_ops = self._batch_set_operands(prog, table, bindings, order)
@@ -1274,12 +1311,12 @@ class PallasBackend(NumpyBackend):
         thr = np.stack(thr_cols, axis=1)
         masks = self._launch(entry, tuple(static), thr, set_ops=set_ops)
         if self._stats is not None:
-            bumps = {"device_batch_scans": 1, "device_batch_rows": B}
+            bumps = {"device_batch_scans": 1, "device_batch_rows": B,
+                     "kernel_atoms": len(atoms) + len(prog.isin_atoms)}
+            bumps.update(_lane_bumps(f for _, f, _, _ in lanes))
             if prog.isin_atoms:
                 bumps["member_fused_scans"] = 1
                 bumps["member_fused_sets"] = len(prog.isin_atoms) * B
-            if any(self._f32_col(table, a.col) for a in atoms):
-                bumps["float_lane_scans"] = 1
             self._stats.bump(**bumps)
         return list(masks)
 
@@ -1301,8 +1338,7 @@ class PallasBackend(NumpyBackend):
         pos = 0
         max_len = 1
         for m, a in enumerate(prog.isin_atoms):
-            flavor = (self._col_flavor(table, a.col)
-                      if a.kind != "col" else None)
+            flavor = self._set_flavor(table, a.col)
             if flavor is None:
                 return None
             col_idxs.append(order[a.col])
@@ -1657,27 +1693,30 @@ class PallasBackend(NumpyBackend):
     # ------------------------------------------------------------------ #
     # launch plumbing
     # ------------------------------------------------------------------ #
-    def _int32_col(self, table: Table, col: str) -> bool:
-        """Is a column exactly representable in the kernel's int32 lanes?
-        Cached per (table, row watermark, col) — the range scan runs once
-        per table, and growth under a stable identity misses."""
-        ck = (table_uid(table), int(table.nrows), col)
+    def _verdict(self, table: Table, key: Tuple, compute: Callable[[], object]):
+        """A column verdict cached per (table, row watermark, ``key``):
+        columns are immutable, so the O(N) check runs once per table, and
+        growth under a stable identity misses."""
+        ck = (table_uid(table), int(table.nrows)) + key
         entry = self._col_ok.get(ck)
         if entry is not None and entry[0]() is table:
             return entry[1]
-        arr = table.cols.get(col)
-        ok = (
-            arr is not None
-            and arr.dtype.kind in "iu"
-            and np.abs(arr).max(initial=0) < 2**31
-        )
+        v = compute()
         with self._lock:
             self._col_ok[ck] = (
                 weakref.ref(table,
                             lambda _, k=ck, d=self._col_ok: d.pop(k, None)),
-                ok,
+                v,
             )
-        return ok
+        return v
+
+    def _int32_col(self, table: Table, col: str) -> bool:
+        """Is a column exactly representable in the kernel's int32 lanes?"""
+        def fit():
+            arr = table.cols.get(col)
+            return bool(arr is not None and arr.dtype.kind in "iu"
+                        and np.abs(arr).max(initial=0) < 2**31)
+        return self._verdict(table, (col,), fit)
 
     @staticmethod
     def _kernel_value(v) -> Optional[int]:
@@ -1697,48 +1736,117 @@ class PallasBackend(NumpyBackend):
         return i
 
     def _f32_col(self, table: Table, col: str) -> bool:
-        """Is a column a float32 lane for the key-space kernel path?
-        (float64 columns stay on the host oracle — no exact int64 key lane
-        exists in the int32 kernel fragment)."""
-        ck = (table_uid(table), int(table.nrows), col, "f32")
-        entry = self._col_ok.get(ck)
-        if entry is not None and entry[0]() is table:
-            return entry[1]
-        arr = table.cols.get(col)
-        ok = arr is not None and arr.dtype == np.float32
-        with self._lock:
-            self._col_ok[ck] = (
-                weakref.ref(table,
-                            lambda _, k=ck, d=self._col_ok: d.pop(k, None)),
-                ok,
-            )
-        return ok
+        """Is a column a float32 lane for the key-space kernel path?"""
+        def fit():
+            arr = table.cols.get(col)
+            return bool(arr is not None and arr.dtype == np.float32)
+        return self._verdict(table, (col, "f32"), fit)
+
+    def _dec_scale(self, table: Table, col: str) -> Optional[int]:
+        """The scale at which a float64 column is a decimal lane: every
+        value exactly ``k / scale`` for int32 ``k`` at the smallest of the
+        store's scales (``core.store.decimal_scale``); None otherwise."""
+        def fit():
+            from .store import decimal_scale
+
+            arr = table.cols.get(col)
+            if arr is None or arr.dtype != np.float64:
+                return None
+            got = decimal_scale(arr)
+            return None if got is None else got[0]
+        return self._verdict(table, (col, "dec"), fit)
+
+    def _pair_col(self, table: Table, a: str, b: str) -> bool:
+        """Does ``a - b`` of two int32-exact columns fit an int32 lane?"""
+        def fit():
+            if not (self._int32_col(table, a) and self._int32_col(table, b)):
+                return False
+            d = (table.cols[a].astype(np.int64)
+                 - table.cols[b].astype(np.int64))
+            return d.size == 0 or (int(d.min()) >= INT32_MIN
+                                   and int(d.max()) <= INT32_MAX)
+        return self._verdict(table, (("pair", a, b),), fit)
 
     def _col_flavor(self, table: Table, col: str) -> Optional[str]:
         """Kernel lane flavor of a column: ``"int"`` (raw int32 lane),
-        ``"f32"`` (sign-folded key lane), or None (out of fragment)."""
+        ``"f32"`` (sign-folded key lane), ``"dec"`` (float64 ``k / scale``
+        held as ``k``), or None (out of fragment)."""
         if self._int32_col(table, col):
             return "int"
         if self._f32_col(table, col):
             return "f32"
+        if self._dec_scale(table, col) is not None:
+            return "dec"
         return None
 
-    def _split_cmp(self, prog, table, binding):
+    def _set_flavor(self, table: Table, col: str) -> Optional[str]:
+        """Lane flavor under which membership runs in-grid: int and f32
+        lanes; a decimal lane's sets stay on the host."""
+        flavor = self._col_flavor(table, col)
+        return flavor if flavor in ("int", "f32") else None
+
+    def _lane_atoms(self, table: Table, a: CmpAtom, v):
+        """The kernel form of comparison atom ``a`` bound to ``v``:
+        ``(lane, flavor, ((op, int32 threshold), ...))``, or None when it
+        stays on the host.  A column pair ``a <op> b`` becomes
+        ``(a - b) <op> 0`` on its difference lane."""
+        if a.kind == "col":
+            if self._pair_col(table, a.col, a.rhs):
+                # the slab key of the difference lane is a tuple, so it
+                # never equals a column name
+                return ("pair", a.col, a.rhs), "pair", ((a.op, 0),)
+            return None
+        flavor = self._col_flavor(table, a.col)
+        if flavor == "int":
+            t = self._kernel_value(v)
+            return None if t is None else (a.col, flavor, ((a.op, t),))
+        if flavor == "f32":
+            p = _f32_atoms(a.op, v)
+            return None if p is None else (a.col, flavor, p)
+        if flavor == "dec" and v is not None and not _is_setlike(v):
+            ot = self._scaled_thr(_DecimalLane(self._dec_scale(table, a.col)),
+                                  a.op, v)
+            return None if ot is None else (a.col, flavor, (ot,))
+        return None
+
+    @staticmethod
+    def _col_reason(table: Table, col: str, v=None) -> str:
+        """Why a numeric atom on ``col`` (bound to ``v``) left the fragment:
+        a float column or threshold, else an integer beyond int32."""
+        kind = getattr(getattr(table.cols.get(col), "dtype", None),
+                       "kind", "")
+        if kind == "f" or isinstance(v, (float, np.floating)):
+            return "float"
+        if (kind in ("i", "u") and not isinstance(v, (bool, np.bool_))
+                and isinstance(v, (int, np.integer, type(None)))):
+            return "wide_int"
+        return "other"
+
+    def _host_reason(self, table: Table, a: CmpAtom, v) -> str:
+        """Why comparison atom ``a`` bound to ``v`` stays on the host."""
+        if a.kind == "col":
+            return "col_pair"
+        if v is _UNBOUND:
+            return "unbound"
+        if _is_setlike(v):
+            return "set_param"
+        return self._col_reason(table, a.col, v)
+
+    def _split_cmp(self, prog, table, binding, why=None):
+        """Comparison atoms the kernel carries, as ``(atom, lane, flavor,
+        pairs)`` from ``_lane_atoms``, and those left on the host;
+        ``why`` counts the host ones by reason."""
         kernel, fallback = [], []
         for a in prog.cmp_atoms:
-            v = _UNBOUND
-            if a.kind == "lit":
-                v = a.rhs
-            elif a.kind == "param" and a.rhs in binding:
-                v = binding[a.rhs]
-            ok = False
-            if a.kind != "col" and v is not _UNBOUND:
-                flavor = self._col_flavor(table, a.col)
-                if flavor == "int":
-                    ok = self._kernel_value(v) is not None
-                elif flavor == "f32":
-                    ok = _f32_atoms(a.op, v) is not None
-            (kernel if ok else fallback).append(a)
+            v = binding.get(a.rhs, _UNBOUND) if a.kind == "param" else a.rhs
+            lane = self._lane_atoms(table, a, v) if v is not _UNBOUND else None
+            if lane is not None:
+                kernel.append((a,) + lane)
+                continue
+            fallback.append(a)
+            if why is not None:
+                r = self._host_reason(table, a, v)
+                why[r] = why.get(r, 0) + 1
         return kernel, fallback
 
     def _prepared_set(self, vals, flavor: str) -> Optional[np.ndarray]:
@@ -1754,28 +1862,27 @@ class PallasBackend(NumpyBackend):
             self._sets[ck] = (vals, keys)
         return keys
 
-    def _split_isin(self, prog, table, binding):
+    def _split_isin(self, prog, table, binding, why=None):
         """Partition membership atoms into fused-kernel candidates
         ``[(atom, keys)]`` and host-fallback atoms, under the launch's set
-        slab budget."""
+        slab budget; ``why`` counts the host ones by reason."""
         kernel, fallback = [], []
         budget = self.SET_SLAB_LIMIT
         for a in prog.isin_atoms:
-            flavor = (self._col_flavor(table, a.col)
-                      if a.kind != "col" else None)
-            vals = None
-            if flavor is not None:
-                if a.kind == "lit":
-                    vals = a.rhs
-                elif a.rhs in binding:
-                    vals = binding[a.rhs]
+            flavor = self._set_flavor(table, a.col)
+            vals = a.rhs if a.kind == "lit" else binding.get(a.rhs, _UNBOUND)
             keys = (self._prepared_set(vals, flavor)
-                    if vals is not None else None)
-            if keys is None or keys.size > budget:
-                fallback.append(a)
-            else:
+                    if flavor is not None and vals is not _UNBOUND else None)
+            if keys is not None and keys.size <= budget:
                 budget -= int(keys.size)
                 kernel.append((a, keys))
+                continue
+            fallback.append(a)
+            if why is not None:
+                r = ("set_budget" if keys is not None
+                     else self._col_reason(table, a.col) if flavor is None
+                     else "unbound" if vals is _UNBOUND else "set_param")
+                why[r] = why.get(r, 0) + 1
         return kernel, fallback
 
     def _build_entry(self, slab: np.ndarray) -> _KernelSlab:
@@ -1795,12 +1902,22 @@ class PallasBackend(NumpyBackend):
             self._stats.bump(h2d_bytes=padded.nbytes)
         return _KernelSlab(jnp.asarray(padded), lo, hi, n)
 
-    def _table_lane(self, table: Table, c: str) -> np.ndarray:
-        """int32 kernel lane for one column: raw values for int columns,
-        sign-folded total-order keys for float32 columns."""
+    def _table_lane(self, table: Table, c) -> np.ndarray:
+        """int32 kernel lane for one slab key: raw values for int columns,
+        sign-folded total-order keys for float32 columns, ``k`` of
+        ``k / scale`` for decimal columns, and ``a - b`` for a pair key."""
+        if isinstance(c, tuple):
+            _, a, b = c
+            return (table.cols[a].astype(np.int64)
+                    - table.cols[b].astype(np.int64)).astype(np.int32)
         arr = np.asarray(table.cols[c])
         if arr.dtype == np.float32:
             return _f32_key(arr)
+        if arr.dtype.kind == "f":
+            from .store import scaled_codes
+
+            return scaled_codes(arr, self._dec_scale(table, c))[0].astype(
+                np.int32)
         return arr.astype(np.int32)
 
     def _slab_entry(self, table: Table, cols: Tuple[str, ...]) -> _KernelSlab:
@@ -1946,25 +2063,21 @@ class PallasBackend(NumpyBackend):
                                  default=1))
         return _SetOps(tuple(col_idxs), slab, off, ln, iters)
 
-    def _kernel_scan(self, atoms: List[CmpAtom], table: Table, binding,
+    def _kernel_scan(self, kernel: Sequence, table: Table,
                      isin: Sequence = ()):
-        cols = tuple(sorted({a.col for a in atoms}
-                            | {a.col for a, _ in isin}))
+        """One launch for the comparison atoms ``kernel`` (``(atom, lane,
+        flavor, pairs)`` from ``_split_cmp``) and the membership atoms
+        ``isin`` (``(atom, keys)``)."""
+        cols = _lane_order({lane for _, lane, _, _ in kernel}
+                           | {a.col for a, _ in isin})
         order = {c: i for i, c in enumerate(cols)}
         entry = self._slab_entry(table, cols)
         static: List[Tuple[int, int]] = []
         thr: List[int] = []
-        n_f32 = 0
-        for a in atoms:
-            v = a.rhs if a.kind == "lit" else binding[a.rhs]
-            if self._f32_col(table, a.col):
-                n_f32 += 1
-                for op, k in _f32_atoms(a.op, v):
-                    static.append((order[a.col], op))
-                    thr.append(k)
-            else:
-                static.append((order[a.col], a.op))
-                thr.append(int(v))
+        for _, lane, _, pairs in kernel:
+            for op, t in pairs:
+                static.append((order[lane], op))
+                thr.append(t)
         set_ops = (self._set_operands([order[a.col] for a, _ in isin],
                                       [keys for _, keys in isin])
                    if isin else None)
@@ -1974,12 +2087,10 @@ class PallasBackend(NumpyBackend):
             static.append((set_ops.set_cols[0], _GE))
             thr.append(INT32_MIN)
         if self._stats is not None:
-            bumps: Dict[str, int] = {}
+            bumps = _lane_bumps(flavor for _, _, flavor, _ in kernel)
             if isin:
                 bumps["member_fused_scans"] = 1
                 bumps["member_fused_sets"] = len(isin)
-            if n_f32:
-                bumps["float_lane_scans"] = 1
             if bumps:
                 self._stats.bump(**bumps)
         return self._launch(entry, tuple(static),
@@ -2066,6 +2177,14 @@ class ScanStats:
     member_fused_scans: int = 0
     member_fused_sets: int = 0
     float_lane_scans: int = 0
+    # launches that carried a column-pair difference lane / a decimal lane
+    pair_lane_scans: int = 0
+    decimal_lane_scans: int = 0
+    # comparison and membership atoms a launch evaluated (once per scan
+    # call or fused batch launch), and those numpy evaluated over the
+    # table's rows instead (every atom of a scan that launched nothing)
+    kernel_atoms: int = 0
+    host_atoms: int = 0
     # run-space rle scans on encoded stores: per-column run launches and the
     # rows the host expansion produced without ever decoding the column
     rle_run_scans: int = 0
@@ -2523,6 +2642,8 @@ class ScanEngine:
                 if masks is not None:
                     trace.note(route="device_batch")
                     return [np.flatnonzero(m) for m in masks]
+                self.stats.bump(host_atoms=len(prog.cmp_atoms)
+                                + len(prog.isin_atoms))
             # no usable equality: one shared pass for the static conjunction
             static_mask = np.ones(n, dtype=bool)
             for a in prog.static_cmp:

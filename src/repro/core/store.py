@@ -461,8 +461,9 @@ class ScaledColumn(EncodedColumn):
     """Floats that are exactly ``k / scale`` (integral floats, money with two
     decimals) stored as an encoded *integer* column.  Encode verifies bitwise
     round-tripping (``decode() == original`` elementwise), so the encoding is
-    lossless by construction; comparison atoms defer to the decoded oracle —
-    re-scaling a float threshold exactly is not generally possible."""
+    lossless by construction; device scans translate a float threshold onto
+    the integers through a verified boundary walk
+    (``PallasBackend._scaled_thr``)."""
 
     kind = "scaled"
 
@@ -516,6 +517,30 @@ class ColumnStats:
 _SCALES = (1, 100)  # integral floats, money with two decimals
 
 
+def scaled_codes(arr: np.ndarray, scale: int) -> Tuple[np.ndarray, np.ndarray]:
+    """``(k, exact)``: ``k = round(arr * scale)`` as floats, and where each
+    value of ``arr`` is exactly ``k / scale`` with ``k`` inside int32 (never
+    at NaN or +-inf).  The one fit test of the ``scaled`` encoding and of
+    the scan kernel's decimal lanes (``core/scan.py``)."""
+    with np.errstate(over="ignore", invalid="ignore"):
+        k = np.round(arr * scale)
+        exact = (np.abs(k) < 2**31) & (k / scale == arr)
+    return k, exact
+
+
+def decimal_scale(arr: np.ndarray) -> Optional[Tuple[int, np.ndarray]]:
+    """``(scale, k)`` for the smallest scale of ``_SCALES`` at which every
+    value of the float column ``arr`` is exactly ``k / scale`` with int32
+    ``k``; None when none fits (a NaN or +-inf never does)."""
+    if arr.dtype.kind != "f":
+        return None
+    for scale in _SCALES:
+        k, exact = scaled_codes(arr, scale)
+        if exact.all():
+            return scale, k
+    return None
+
+
 def _int_span(arr: np.ndarray) -> Tuple[int, int, Optional[int]]:
     vmin, vmax = int(arr.min()), int(arr.max())
     d = arr.astype(np.int64)[1:] - arr.astype(np.int64)[:-1]
@@ -539,18 +564,13 @@ def analyze_column(arr: np.ndarray) -> ColumnStats:
         st.vmin, st.vmax, st.max_delta = _int_span(arr)
         if not st.is_sorted:
             st.max_delta = None
-    elif k == "f" and not st.has_nan and bool(np.isfinite(arr).all()):
-        for scale in _SCALES:
-            scaled = np.round(arr * scale)
-            if (
-                float(np.abs(scaled).max(initial=0)) < 2**31
-                and np.array_equal(scaled / scale, arr)
-            ):
-                st.scale = scale
-                st.vmin, st.vmax, st.max_delta = _int_span(scaled)
-                if not st.is_sorted:
-                    st.max_delta = None
-                break
+    elif k == "f" and not st.has_nan:
+        fit = decimal_scale(arr)
+        if fit is not None:
+            st.scale, scaled = fit
+            st.vmin, st.vmax, st.max_delta = _int_span(scaled)
+            if not st.is_sorted:
+                st.max_delta = None
     return st
 
 
@@ -727,11 +747,10 @@ def _append_fast(enc: EncodedColumn, arr: np.ndarray) -> Optional[EncodedColumn]
             np.concatenate([enc.bits, np.packbits(arr.astype(bool))]),
             enc.n + len(arr))
     if isinstance(enc, ScaledColumn):
-        if arr.dtype.kind != "f" or not bool(np.isfinite(arr).all()):
+        if arr.dtype.kind != "f":
             return None
-        scaled = np.round(arr * enc.scale)
-        if (float(np.abs(scaled).max(initial=0)) >= 2**31
-                or not np.array_equal(scaled / enc.scale, arr)):
+        scaled, exact = scaled_codes(arr, enc.scale)
+        if not exact.all():
             return None  # delta rows aren't exactly k/scale: re-encode
         inner = _append_fast(enc.inner, scaled.astype(enc.inner.dtype))
         if inner is None:

@@ -471,8 +471,8 @@ def test_float32_lane_engaged_not_fallback():
 
 
 def test_float64_still_falls_back():
-    # float64 columns stay outside the key-lane fragment (no exact int32
-    # key embedding); answers must still match through the host fallback
+    # a float64 column that is not decimal (k / scale) stays outside the
+    # lane fragment; answers must still match through the host fallback
     rng = np.random.default_rng(41)
     t = Table({"f": rng.normal(size=N), "k": rng.integers(0, 9, N).astype(np.int32)},
               {}, "t")

@@ -155,64 +155,92 @@ def _tuple_narrow(table: Table, idx: np.ndarray, atoms, vals) -> np.ndarray:
     return idx
 
 
+def _holds_on(pred: Expr, table: Table, idx: np.ndarray,
+              binding: Dict[str, object]) -> np.ndarray:
+    """The rows ``idx`` of ``table`` where ``pred`` holds, evaluated on
+    those rows alone: only the columns ``pred`` reads, gathered at ``idx``."""
+    if not len(idx):
+        return idx
+    env = {c: table.cols[c][idx] for c in cols_of(pred)}
+    return idx[np.asarray(eval_np(pred, env, binding, n=len(idx)), bool)]
+
+
 def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
                param_stage: Dict[str, int], stage_sel: Dict[int, Table],
                param_col: Dict[str, str],
                scan=None, analysis=None,
                engine: Optional[ScanEngine] = None) -> np.ndarray:
-    """Evaluate a concretized predicate.
+    """Sorted unique row indices (int64) of ``table`` where a concretized
+    predicate holds.
 
     Array-bound params appearing only in equality atoms keep set semantics
     (exact per atom).  Params from the *same* materialized stage that appear
     in non-equality atoms, or co-occur (cross-product hazard), are bound
     PER STAGE ROW and the masks OR'd — the paper's "replace variables with
-    the corresponding rows".  ``scan`` is the compiled-scan backend for the
-    plain-conjunction fragments (defaults to the tree evaluator);
-    ``analysis`` the binding-independent pair :func:`_binding_groups`
-    accepts, for callers that evaluate one predicate many times; ``engine``
-    the owner of the sorted-column indexes the tuple groups probe
-    (:func:`_tuple_members`)."""
+    the corresponding rows".  When tuple groups give candidate rows, the
+    remaining atoms are evaluated on those candidates alone (their columns
+    gathered at the candidates), as :meth:`PredTrace.query_batch` does; only
+    a predicate without candidates is scanned over the whole table.
+    ``scan`` is the compiled-scan backend for those whole-table scans
+    (defaults to the tree evaluator); ``analysis`` the binding-independent
+    pair :func:`_binding_groups` accepts, for callers that evaluate one
+    predicate many times; ``engine`` the owner of the sorted-column indexes
+    the tuple groups probe (:func:`_tuple_members`) and of the counters."""
     if scan is None:
         scan = lambda p, t, b: np.asarray(eval_np(p, t.cols, b, n=t.nrows), bool)
     tuple_groups, rowwise = _binding_groups(pred, binding, param_stage,
                                             analysis=analysis)
     if not rowwise and not tuple_groups:
-        return scan(pred, table, binding)
+        return np.flatnonzero(scan(pred, table, binding))
 
-    consumed_atoms = []
-    cand = None  # row indices every tuple group admits; None: all rows
+    from .expr import land
+
+    conj = conjuncts(pred)
+    groups = []  # per tuple group with atoms: [(atom, lhs, stage values)]
+    stage_rows = 0
     for sid, plist in tuple_groups.items():
         sel = stage_sel[sid]
-        with trace.span("lineage.tuple", rows=sel.nrows) as ts:
-            atoms = []
-            for a in conjuncts(pred):
-                ap = params_of(a)
-                if len(ap) == 1 and next(iter(ap)) in plist and isinstance(a, BinOp):
-                    p = next(iter(ap))
-                    lhs = a.left if isinstance(a.right, Param) else a.right
-                    atoms.append((lhs, np.asarray(sel.cols[param_col[p]])))
-                    consumed_atoms.append(a)
-            route, idx = _tuple_members(table, atoms, engine)
-            cand = idx if cand is None else np.intersect1d(
-                cand, idx, assume_unique=True)
-            ts.set(route=route)
-    if cand is None:
-        mask = np.ones(table.nrows, dtype=bool)
-    else:
-        mask = np.zeros(table.nrows, dtype=bool)
-        mask[cand] = True
+        atoms = []
+        for a in conj:
+            ap = params_of(a)
+            if len(ap) == 1 and next(iter(ap)) in plist and isinstance(a, BinOp):
+                p = next(iter(ap))
+                lhs = a.left if isinstance(a.right, Param) else a.right
+                atoms.append((a, lhs, np.asarray(sel.cols[param_col[p]])))
+        if atoms:  # a membership-only group leaves its IN atoms to the rest
+            groups.append(atoms)
+            stage_rows += sel.nrows
+    consumed = [a for atoms in groups for a, _, _ in atoms]
+    rest = [a for a in conj if a not in consumed]
+    rest_pred = land(*rest) if rest else None
+    on_cand = bool(groups) and not rowwise
+    if engine is not None and tuple_groups:
+        engine.stats.bump(**{"tuple_rest_cand" if on_cand
+                             else "tuple_rest_table": 1})
 
-    rest = [a for a in conjuncts(pred) if a not in consumed_atoms]
-    rest_params = set()
-    for a in rest:
-        rest_params |= params_of(a)
-    rowwise_params = [p for plist in rowwise.values() for p in plist]
-    if not (rest_params & set(rowwise_params)):
-        if rest:
-            from .expr import land
-
-            mask &= scan(land(*rest), table, binding)
-        return mask
+    cand = None  # row indices every tuple group admits; None: no groups
+    if groups:
+        with trace.span("lineage.tuple", rows=stage_rows) as ts:
+            routes = []
+            for atoms in groups:
+                route, idx = _tuple_members(
+                    table, [(lhs, v) for _, lhs, v in atoms], engine)
+                routes.append(route)
+                cand = idx if cand is None else np.intersect1d(
+                    cand, idx, assume_unique=True)
+            if not on_cand:
+                rest_rows = table.nrows
+            elif rest_pred is None:
+                rest_rows = 0
+            else:
+                rest_rows = len(cand)
+                cand = _holds_on(rest_pred, table, cand, binding)
+            ts.set(route="+".join(routes),
+                   rest="cand" if on_cand else "table", rest_rows=rest_rows)
+    if on_cand:
+        return cand
+    if not rowwise:  # membership-only groups: no candidates to run on
+        return np.flatnonzero(scan(rest_pred, table, binding))
 
     # non-equality params (window ranges etc.): bind per stage row and OR
     assert len(rowwise) == 1, (
@@ -224,16 +252,13 @@ def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
     cols = [param_col[p] for p in plist]
     rows = np.unique(np.stack([np.asarray(sel.cols[c]) for c in cols], axis=1), axis=0)
     rmask = np.zeros(table.nrows, dtype=bool)
-    from .expr import land
-
-    rest_pred = land(*rest)
     with trace.span("lineage.rowwise", rows=len(rows)):
         for r in rows:
             b2 = dict(binding)
             for p, val in zip(plist, r):
                 b2[p] = val.item() if hasattr(val, "item") else val
             rmask |= scan(rest_pred, table, b2)
-    return mask & rmask
+    return np.flatnonzero(rmask) if cand is None else cand[rmask[cand]]
 
 
 @dataclass
@@ -767,10 +792,10 @@ class PredTrace:
                 table, route = stobj.to_table(), "decoded"
             else:
                 table, route = stobj, "plain"
-            m = _eval_pred(st.run_pred, table, binding, param_stage,
-                           stage_sel, param_col, scan=self._scan,
-                           engine=self.scan_engine)
-            sel = table.mask(m)
+            idx = _eval_pred(st.run_pred, table, binding, param_stage,
+                             stage_sel, param_col, scan=self._scan,
+                             engine=self.scan_engine)
+            sel = table.take(idx)
             sp.set(route=route, rows=sel.nrows)
             return sel
 
@@ -825,10 +850,7 @@ class PredTrace:
                 used_stage_nodes.add(st.node_id)
                 continue
             if any(_guard_dead(binding.get(g)) for g in st.guards):
-                if isinstance(stobj, StoredTable):
-                    sel = stobj.take(np.empty(0, dtype=np.int64))
-                else:
-                    sel = stobj.mask(np.zeros(stobj.nrows, dtype=bool))
+                sel = stobj.take(np.empty(0, dtype=np.int64))
             else:
                 sel = self._stage_select(st, stobj, binding, param_stage,
                                          stage_sel, param_col)
@@ -852,10 +874,10 @@ class PredTrace:
                 if sp.pred == FALSE or any(_guard_dead(binding.get(g)) for g in sp.guards):
                     rids = np.array([], dtype=np.int64)
                 else:
-                    m = _eval_pred(sp.pred, t, binding, param_stage, stage_sel,
-                                   param_col, scan=scan,
-                                   engine=self.scan_engine)
-                    rids = t.rids()[m]
+                    idx = _eval_pred(sp.pred, t, binding, param_stage,
+                                     stage_sel, param_col, scan=scan,
+                                     engine=self.scan_engine)
+                    rids = t.rids()[idx]
                 lineage[sp.table] = (
                     np.union1d(lineage[sp.table], rids)
                     if sp.table in lineage else np.unique(rids)
@@ -978,9 +1000,9 @@ class PredTrace:
                                   for k, v in stobj.cols.items()},
                                  stobj.dicts, stobj.name)
                 vcache[vkey] = view
-            m = _eval_pred(st.run_pred, view, binding, param_stage,
-                           stage_sel, param_col, analysis=st_pair)
-            if m.any():
+            idx = _eval_pred(st.run_pred, view, binding, param_stage,
+                             stage_sel, param_col, analysis=st_pair)
+            if len(idx):
                 return None  # stage_delta_match: binding would change
 
         # 2. source predicates: scan only the delta view, union new rids
@@ -1048,11 +1070,12 @@ class PredTrace:
             # planning, pruning and sorted indexes would cost more than the
             # scan itself, so the rescan route uses the tree evaluator and
             # whole-column tuple membership directly
-            m = _eval_pred(sp.pred, scan_t, binding, param_stage, stage_sel,
-                           param_col, scan=self._scan if serial else None,
-                           analysis=sp_pair,
-                           engine=self.scan_engine if serial else None)
-            rids = scan_t.rids()[m]
+            idx = _eval_pred(sp.pred, scan_t, binding, param_stage,
+                             stage_sel, param_col,
+                             scan=self._scan if serial else None,
+                             analysis=sp_pair,
+                             engine=self.scan_engine if serial else None)
+            rids = scan_t.rids()[idx]
             choice.done(time.perf_counter() - t1)
             if serial:
                 scanned = total_parts
@@ -1285,7 +1308,6 @@ class PredTrace:
                 from .expr import land
 
                 rest_pred = land(*rest)
-                rest_cols = [c for c in cols_of(rest_pred) if c in table.cols]
 
             def lhs_vals(lhs, idx):
                 if isinstance(lhs, Col):
@@ -1308,14 +1330,7 @@ class PredTrace:
                 self.scan_engine.stats.bump(tuple_index_groups=len(bs))
             if rest_pred is not None:
                 for b in bs:
-                    idx = out[b]
-                    if not len(idx):
-                        continue
-                    env = {c: table.cols[c][idx] for c in rest_cols}
-                    keep = np.asarray(
-                        eval_np(rest_pred, env, bindings[b], n=len(idx)), bool
-                    )
-                    out[b] = idx[keep]
+                    out[b] = _holds_on(rest_pred, table, out[b], bindings[b])
             return out
 
         def batch_indices(pred, table, guards) -> List[Optional[np.ndarray]]:
@@ -1355,10 +1370,9 @@ class PredTrace:
                     for b, idx in res.items():
                         idxs[b] = idx
             for b in per_row:
-                m = _eval_pred(pred, table, bindings[b], param_stage,
-                               stage_sels(b), param_col, scan=scan,
-                               engine=self.scan_engine)
-                idxs[b] = np.nonzero(m)[0]
+                idxs[b] = _eval_pred(pred, table, bindings[b], param_stage,
+                                     stage_sels(b), param_col, scan=scan,
+                                     engine=self.scan_engine)
             if simple:
                 batched = self.scan_engine.scan_batch_idx(
                     pred, table, [bindings[b] for b in simple]

@@ -2182,7 +2182,8 @@ class ScanStats:
     decimal_lane_scans: int = 0
     # comparison and membership atoms a launch evaluated (once per scan
     # call or fused batch launch), and those numpy evaluated over the
-    # table's rows instead (every atom of a scan that launched nothing)
+    # table's rows instead (every atom of a scan that launched nothing);
+    # atoms the lineage walk evaluates on tuple candidates count in neither
     kernel_atoms: int = 0
     host_atoms: int = 0
     # run-space rle scans on encoded stores: per-column run launches and the
@@ -2212,6 +2213,12 @@ class ScanStats:
     # atom answered from the sorted-column index, or by a whole-column isin
     tuple_index_groups: int = 0
     tuple_isin_groups: int = 0
+    # predicates with a tuple group: the other atoms evaluated on the
+    # group's candidate rows (no scan of the table, so they count as
+    # neither kernel_atoms nor host_atoms), or scanned over the whole
+    # table (row-wise bindings, or a group that gave no candidates)
+    tuple_rest_cand: int = 0
+    tuple_rest_table: int = 0
     # the engine's bounded caches, registered for the stats() snapshot
     caches: Dict[str, "LRUCache"] = field(default_factory=dict, repr=False)
     # counter increments are read-modify-write; concurrent scans (the

@@ -24,13 +24,12 @@ from . import ops as O
 from . import trace
 from .executor import ExecResult, Executor
 from .expr import (
-    BinOp, Col, Expr, FALSE, IsIn, Param, cols_of, conjuncts, eval_np,
+    BinOp, Col, Expr, FALSE, IsIn, Param, cols_of, conjuncts, eval_np, land,
     params_of,
 )
 from .iterative import IterativeInference, IterativePlan, RefineResult, refine
 from .plan import (
-    LineageInference, LineagePlan, MaterializationPlan, SourcePred, Stage,
-    plan_materialization,
+    LineageInference, LineagePlan, MaterializationPlan, plan_materialization,
 )
 from .scan import ScanEngine, prune_zone_maps
 from .store import IntermediateStore, StoredTable
@@ -110,26 +109,29 @@ def _zone_restrict(table: Table, atoms) -> np.ndarray:
 
 
 def _tuple_members(table: Table, atoms, engine: Optional[ScanEngine] = None):
-    """``(route, idx)``: sorted row indices of ``table`` whose left-side
-    values form a tuple of the stage selection, one ``(lhs, stage values)``
-    atom per tuple column.
+    """``(route, idxs)``: per binding, the sorted row indices of ``table``
+    whose left-side values form a tuple of that binding's stage selection.
+    ``atoms[b]`` holds binding ``b``'s ``(lhs, stage values)`` pairs, one
+    per tuple column; every binding has the same left sides.
 
     With an ``engine`` and a plain-column leading atom, the leading atom's
-    candidates come from the engine's cached sorted-column index (route
-    ``index``); otherwise from a whole-column ``isin`` after zone pruning
-    (route ``isin``), which suits tables seen once, such as fresh delta
-    views."""
-    if engine is not None and atoms and isinstance(atoms[0][0], Col):
-        col = atoms[0][0].name
-        idx = engine.member_batch_idx(table, atoms[0][0], [atoms[0][1]])[0]
-        route, idx = "index", _tuple_narrow(table, idx, atoms,
-                                            [table.cols[col][idx]])
+    candidates of every binding come from one probe of the engine's cached
+    sorted-column index (route ``index``); otherwise from a whole-column
+    ``isin`` after zone pruning (route ``isin``), which suits tables seen
+    once, such as fresh delta views."""
+    lhs0 = atoms[0][0][0]
+    if engine is not None and isinstance(lhs0, Col):
+        route, col = "index", table.cols[lhs0.name]
+        cands = engine.member_batch_idx(table, lhs0, [a[0][1] for a in atoms])
+        idxs = [_tuple_narrow(table, c, a, [col[c]])
+                for c, a in zip(cands, atoms)]
     else:
         route = "isin"
-        idx = _tuple_narrow(table, _zone_restrict(table, atoms), atoms, [])
+        idxs = [_tuple_narrow(table, _zone_restrict(table, a), a, [])
+                for a in atoms]
     if engine is not None:
-        engine.stats.bump(**{f"tuple_{route}_groups": 1})
-    return route, idx
+        engine.stats.bump(**{f"tuple_{route}_groups": len(atoms)})
+    return route, idxs
 
 
 def _tuple_narrow(table: Table, idx: np.ndarray, atoms, vals) -> np.ndarray:
@@ -165,82 +167,132 @@ def _holds_on(pred: Expr, table: Table, idx: np.ndarray,
     return idx[np.asarray(eval_np(pred, env, binding, n=len(idx)), bool)]
 
 
-def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
-               param_stage: Dict[str, int], stage_sel: Dict[int, Table],
-               param_col: Dict[str, str],
-               scan=None, analysis=None,
-               engine: Optional[ScanEngine] = None) -> np.ndarray:
-    """Sorted unique row indices (int64) of ``table`` where a concretized
-    predicate holds.
+_NO_ROWS = np.empty(0, dtype=np.int64)
 
-    Array-bound params appearing only in equality atoms keep set semantics
-    (exact per atom).  Params from the *same* materialized stage that appear
-    in non-equality atoms, or co-occur (cross-product hazard), are bound
-    PER STAGE ROW and the masks OR'd — the paper's "replace variables with
-    the corresponding rows".  When tuple groups give candidate rows, the
-    remaining atoms are evaluated on those candidates alone (their columns
-    gathered at the candidates), as :meth:`PredTrace.query_batch` does; only
-    a predicate without candidates is scanned over the whole table.
-    ``scan`` is the compiled-scan backend for those whole-table scans
-    (defaults to the tree evaluator); ``analysis`` the binding-independent
-    pair :func:`_binding_groups` accepts, for callers that evaluate one
-    predicate many times; ``engine`` the owner of the sorted-column indexes
-    the tuple groups probe (:func:`_tuple_members`) and of the counters."""
+
+def _dead(binding: Dict[str, object], guards) -> bool:
+    """Does a guard param of ``binding`` hold a null or an empty set?  The
+    predicate it guards then matches nothing."""
+    return any(_guard_dead(binding.get(g)) for g in guards)
+
+
+def _eval_pred(pred: Expr, table: Table, bindings: Sequence[Dict[str, object]],
+               param_stage: Dict[str, int], param_col: Dict[str, str],
+               stage_sels: Sequence, guards=(), scan=None, analysis=None,
+               engine: Optional[ScanEngine] = None) -> List[np.ndarray]:
+    """Per binding, the sorted unique row indices (int64) of ``table`` where
+    a concretized predicate holds.
+
+    Each binding is classified once (:func:`_binding_groups`):
+
+    * a dead guard (:func:`_dead`) matches nothing;
+    * a plain conjunction goes through ``scan`` (the engine's or the
+      partition executor's K=1 scan) when the call has one binding, and
+      through the engine's :meth:`ScanEngine.scan_batch_idx` when it has
+      several;
+    * tuple groups and row-wise params are evaluated together for the
+      bindings of one shape (:func:`_eval_shape`).
+
+    ``stage_sels[b]`` is a zero-arg callable giving binding ``b``'s stage
+    selections by stage index; only the tuple and row-wise shapes call it.
+    ``scan`` is the compiled-scan backend (defaults to the tree evaluator);
+    ``analysis`` the binding-independent pair :func:`_binding_groups`
+    accepts, for callers that evaluate one predicate many times; ``engine``
+    the owner of the sorted-column indexes, the batched scan and the
+    counters (without one: whole-column ``isin`` and one scan per binding,
+    as suits a fresh delta view)."""
     if scan is None:
         scan = lambda p, t, b: np.asarray(eval_np(p, t.cols, b, n=t.nrows), bool)
-    tuple_groups, rowwise = _binding_groups(pred, binding, param_stage,
-                                            analysis=analysis)
-    if not rowwise and not tuple_groups:
-        return np.flatnonzero(scan(pred, table, binding))
+    if analysis is None:
+        analysis = (params_of(pred), _eq_only_params(pred))
+    out = [_NO_ROWS] * len(bindings)
+    simple: List[int] = []
+    shapes: Dict[Tuple, List[int]] = {}
+    for b, binding in enumerate(bindings):
+        if _dead(binding, guards):
+            continue
+        shape = tuple(
+            tuple(sorted((sid, tuple(sorted(plist))) for sid, plist in g.items()))
+            for g in _binding_groups(pred, binding, param_stage, analysis))
+        if any(shape):
+            shapes.setdefault(shape, []).append(b)
+        else:
+            simple.append(b)
+    if simple and len(bindings) > 1 and engine is not None:
+        found = engine.scan_batch_idx(pred, table, [bindings[b] for b in simple])
+    else:
+        found = [np.flatnonzero(scan(pred, table, bindings[b])) for b in simple]
+    for b, idx in zip(simple, found):
+        out[b] = idx
+    for (tg, rw), bs in shapes.items():
+        for b, idx in zip(bs, _eval_shape(
+                pred, table, [bindings[b] for b in bs], dict(tg), dict(rw),
+                param_col, [stage_sels[b] for b in bs], scan, engine)):
+            out[b] = idx
+    return out
 
-    from .expr import land
 
+def _eval_shape(pred: Expr, table: Table, bindings, tuple_groups, rowwise,
+                param_col, stage_sels, scan, engine) -> List[np.ndarray]:
+    """:func:`_eval_pred` for bindings of one shape: the same tuple groups
+    and row-wise params (stage index -> params).
+
+    Array-bound params appearing only in equality atoms keep set semantics
+    (exact per atom).  Params from the *same* stage that co-occur in
+    equality atoms (cross-product hazard) form a tuple group: each group's
+    leading atom is probed for every binding at once
+    (:func:`_tuple_members`), its other atoms narrow the candidates, and the
+    predicate's remaining atoms are evaluated on the candidates alone.
+    Params from one stage that appear in non-equality atoms are bound PER
+    STAGE ROW and the masks OR'd — the paper's "replace variables with the
+    corresponding rows" — then intersected with any tuple candidates."""
     conj = conjuncts(pred)
-    groups = []  # per tuple group with atoms: [(atom, lhs, stage values)]
-    stage_rows = 0
+    groups = []  # per tuple group with atoms: (stage index, [(atom, lhs, param)])
     for sid, plist in tuple_groups.items():
-        sel = stage_sel[sid]
         atoms = []
         for a in conj:
             ap = params_of(a)
             if len(ap) == 1 and next(iter(ap)) in plist and isinstance(a, BinOp):
-                p = next(iter(ap))
                 lhs = a.left if isinstance(a.right, Param) else a.right
-                atoms.append((a, lhs, np.asarray(sel.cols[param_col[p]])))
+                atoms.append((a, lhs, next(iter(ap))))
         if atoms:  # a membership-only group leaves its IN atoms to the rest
-            groups.append(atoms)
-            stage_rows += sel.nrows
-    consumed = [a for atoms in groups for a, _, _ in atoms]
+            groups.append((sid, atoms))
+    consumed = [a for _, atoms in groups for a, _, _ in atoms]
     rest = [a for a in conj if a not in consumed]
     rest_pred = land(*rest) if rest else None
     on_cand = bool(groups) and not rowwise
     if engine is not None and tuple_groups:
         engine.stats.bump(**{"tuple_rest_cand" if on_cand
-                             else "tuple_rest_table": 1})
+                             else "tuple_rest_table": len(bindings)})
 
-    cand = None  # row indices every tuple group admits; None: no groups
+    cands = None  # per binding, the rows every tuple group admits
     if groups:
-        with trace.span("lineage.tuple", rows=stage_rows) as ts:
+        sels = [s() for s in stage_sels]
+        with trace.span("lineage.tuple", rows=sum(
+                sel[sid].nrows for sel in sels for sid, _ in groups)) as ts:
             routes = []
-            for atoms in groups:
-                route, idx = _tuple_members(
-                    table, [(lhs, v) for _, lhs, v in atoms], engine)
+            for sid, atoms in groups:
+                route, idxs = _tuple_members(table, [
+                    [(lhs, np.asarray(sel[sid].cols[param_col[p]]))
+                     for _, lhs, p in atoms] for sel in sels], engine)
                 routes.append(route)
-                cand = idx if cand is None else np.intersect1d(
-                    cand, idx, assume_unique=True)
+                cands = idxs if cands is None else [
+                    np.intersect1d(c, i, assume_unique=True)
+                    for c, i in zip(cands, idxs)]
             if not on_cand:
-                rest_rows = table.nrows
+                rest_rows = table.nrows * len(bindings)
             elif rest_pred is None:
                 rest_rows = 0
             else:
-                rest_rows = len(cand)
-                cand = _holds_on(rest_pred, table, cand, binding)
+                rest_rows = sum(map(len, cands))
+                cands = [_holds_on(rest_pred, table, c, b)
+                         for c, b in zip(cands, bindings)]
             ts.set(route="+".join(routes),
                    rest="cand" if on_cand else "table", rest_rows=rest_rows)
     if on_cand:
-        return cand
+        return cands
     if not rowwise:  # membership-only groups: no candidates to run on
-        return np.flatnonzero(scan(rest_pred, table, binding))
+        return [np.flatnonzero(scan(rest_pred, table, b)) for b in bindings]
 
     # non-equality params (window ranges etc.): bind per stage row and OR
     assert len(rowwise) == 1, (
@@ -248,17 +300,22 @@ def _eval_pred(pred: Expr, table: Table, binding: Dict[str, object],
         "plan inference should not produce this shape"
     )
     (sid, plist), = rowwise.items()
-    sel = stage_sel[sid]
     cols = [param_col[p] for p in plist]
-    rows = np.unique(np.stack([np.asarray(sel.cols[c]) for c in cols], axis=1), axis=0)
-    rmask = np.zeros(table.nrows, dtype=bool)
-    with trace.span("lineage.rowwise", rows=len(rows)):
-        for r in rows:
-            b2 = dict(binding)
-            for p, val in zip(plist, r):
-                b2[p] = val.item() if hasattr(val, "item") else val
-            rmask |= scan(rest_pred, table, b2)
-    return np.flatnonzero(rmask) if cand is None else cand[rmask[cand]]
+    out = []
+    for j, binding in enumerate(bindings):
+        sel = stage_sels[j]()[sid]
+        rows = np.unique(np.stack([np.asarray(sel.cols[c]) for c in cols],
+                                  axis=1), axis=0)
+        rmask = np.zeros(table.nrows, dtype=bool)
+        with trace.span("lineage.rowwise", rows=len(rows)):
+            for r in rows:
+                b2 = dict(binding)
+                for p, val in zip(plist, r):
+                    b2[p] = val.item() if hasattr(val, "item") else val
+                rmask |= scan(rest_pred, table, b2)
+        out.append(np.flatnonzero(rmask) if cands is None
+                   else cands[j][rmask[cands[j]]])
+    return out
 
 
 @dataclass
@@ -276,9 +333,9 @@ class LineageAnswer:
     plan: Optional[object] = field(default=None, repr=False)
     # query-time context for the warm delta-extension path
     # (:meth:`PredTrace.query_delta`): ``(binding, param_stage, param_col,
-    # stage_sel)`` where ``stage_sel`` is the selection dict or a zero-arg
-    # thunk building it lazily (batch path).  Only precise, fully
-    # materialized answers carry one.
+    # stage_sels)`` where ``stage_sels`` is a zero-arg callable building the
+    # stage selections (stage index -> Table) on first call.  Only precise,
+    # fully materialized answers carry one.
     delta_ctx: Optional[tuple] = field(default=None, repr=False, compare=False)
 
     def total_rows(self) -> int:
@@ -319,29 +376,18 @@ def _is_null(v) -> bool:
         return False
 
 
-def _uniq(v: np.ndarray) -> np.ndarray:
-    """``np.unique`` with fast paths for the overwhelmingly common shapes of
-    stage-binding columns: empty/singleton, and constant (the selected stage
-    rows share the group key)."""
-    if len(v) <= 1:
-        return v
-    if (v[0] == v).all():
-        return v[:1]
-    return np.unique(v)
-
-
-def _clean_binding_value(v):
-    """Normalize a bound value: drop null sentinels from arrays, collapse
-    singleton arrays to scalars."""
-    if isinstance(v, np.ndarray):
-        if v.dtype.kind == "f":
-            v = v[~np.isnan(v)]
-        elif v.dtype.kind in "iu":
-            v = v[v != -1]
-        if len(v) == 1:
-            return v[0].item()
-        return v
-    return v
+def _bind_value(v: np.ndarray):
+    """A stage column's values at the selected rows as a param binding:
+    the one non-null value as a scalar, else the array of distinct non-null
+    values (empty: the param matches nothing).  Nulls are the ``-1`` and
+    NaN sentinels; a constant column (the selected rows share the group
+    key, the common case) skips the sort."""
+    v = np.unique(v) if len(v) > 1 and not (v[0] == v).all() else v[:1]
+    if v.dtype.kind == "f":
+        v = v[~np.isnan(v)]
+    elif v.dtype.kind in "iu":
+        v = v[v != -1]
+    return v[0].item() if len(v) == 1 else v
 
 
 class PredTrace:
@@ -774,140 +820,193 @@ class PredTrace:
             return refine(self.iter_plan, self.catalog, binding,
                           scan=lambda p, t, b: self._scan(p, t, b))
 
-    def _stage_select(self, st: Stage, stobj, binding, param_stage, stage_sel,
-                      param_col) -> Table:
-        """Matching stage rows as a (small) Table.  Encoded stages scan
-        in situ when the binding shape is a plain conjunction (the common
-        case) and only the selected rows are decoded via gather; the
-        tuple/row-wise binding shapes fall back to the decoded table."""
-        with trace.span("lineage.stage", node=st.node_id) as sp:
-            if isinstance(stobj, StoredTable) and self.store is not None:
-                tg, rw = _binding_groups(st.run_pred, binding, param_stage)
-                if not tg and not rw:
-                    m = self.store.scan(st.node_id, st.run_pred, binding,
-                                        self.scan_engine)
-                    sel = stobj.take(np.nonzero(m)[0])
-                    sp.set(route="in_situ", rows=sel.nrows)
-                    return sel
-                table, route = stobj.to_table(), "decoded"
-            else:
-                table, route = stobj, "plain"
-            idx = _eval_pred(st.run_pred, table, binding, param_stage,
-                             stage_sel, param_col, scan=self._scan,
-                             engine=self.scan_engine)
-            sel = table.take(idx)
-            sp.set(route=route, rows=sel.nrows)
-            return sel
-
     def query(self, t_o: Union[int, Dict[str, object]]) -> LineageAnswer:
-        """Precise lineage via materialized intermediates (Algorithm 1).
+        """Precise lineage of one output row via materialized intermediates
+        (Algorithm 1): the stage walk (:meth:`_walk`) over one binding.
 
-        With a byte-budgeted store, source predicates that depend on a
-        dropped stage's params degrade *per table* to the iterative/superset
-        path (``detail["superset_tables"]``); everything whose stage chain is
-        still materialized stays precise."""
+        With one binding, a stored stage whose predicate is a plain
+        conjunction is scanned in situ, and every plain-conjunction scan is
+        the K=1 scan (``ScanEngine.scan``, or the partition executor's),
+        which launches the kernel on a device backend: a lone question has
+        no batch to share an index probe with, and the service sends most
+        questions alone (click traffic coalesces about 1.06 requests per
+        batch).  With a byte-budgeted store, source predicates that depend
+        on a dropped stage's params degrade *per table* to the
+        iterative/superset path (``detail["superset_tables"]``); everything
+        whose stage chain is still materialized stays precise."""
         with trace.span("lineage.query", rows=1):
-            return self._query(t_o)
+            return self._walk([t_o])[0]
 
-    def _query(self, t_o: Union[int, Dict[str, object]]) -> LineageAnswer:
+    def _walk(self, rows: Sequence[Union[int, Dict[str, object]]]
+              ) -> List[LineageAnswer]:
+        """Answer output rows together through the plan's stage chain:
+        bind each row's output params, select each usable stage's rows for
+        every row and bind the params they give (:func:`_bind_value`), then
+        evaluate each source predicate for every row (:func:`_eval_pred`).
+
+        A stage or source predicate whose params a row lacks (it depends on
+        a budget-dropped stage) is skipped for that row; its tables take the
+        iterative/superset answer and are flagged imprecise.  With nothing
+        materialized at all every answer is that path.  Each answer's
+        ``seconds`` is the walk's time over the number of rows."""
         assert self.lineage_plan is not None and self.exec_result is not None
         t0 = time.perf_counter()
+        B = len(rows)
+        if not B:
+            return []
         with trace.span("lineage.bind"):
-            binding = self._output_binding(t_o)
-        scan = self._scan
+            bindings = [self._output_binding(r) for r in rows]
         lp = self.lineage_plan
         dropped = self.mat_plan.dropped if self.mat_plan is not None else set()
-        detail: Dict[str, object] = {}
-
-        # nothing materialized at all (budget 0): the whole query is the
-        # iterative path — identical to ``query_iterative``
         if lp.stages and len(dropped) >= len(lp.stages):
-            rr = self._superset_refine(t_o)
-            detail["superset_tables"] = sorted({sp.table for sp in lp.source_preds})
-            detail["iterations"] = rr.iterations
-            lin = dict(rr.lineage)
-            return LineageAnswer(lin, time.perf_counter() - t0, detail,
-                                 precise={t: False for t in lin})
+            # nothing materialized at all (budget 0): every answer is the
+            # iterative path — identical to ``query_iterative``
+            tables = sorted({sp.table for sp in lp.source_preds})
+            out = []
+            for r in rows:
+                rr = self._superset_refine(r)
+                lin = dict(rr.lineage)
+                out.append(LineageAnswer(
+                    lin, detail={"superset_tables": list(tables),
+                                 "iterations": rr.iterations},
+                    precise={t: False for t in lin}))
+            dt = (time.perf_counter() - t0) / B
+            for ans in out:
+                ans.seconds = dt
+            return out
 
-        # walk the stage chain, binding parameters from selected rows
-        available = set(binding)
+        avail = [set(bd) for bd in bindings]
+        used: List[set] = [set() for _ in range(B)]  # stage nodes each row ran
         param_stage: Dict[str, int] = {}
         param_col: Dict[str, str] = {}
-        stage_sel: Dict[int, Table] = {}
-        used_stage_nodes: set = set()
+        tabs: Dict[int, object] = {}  # stage index -> the table picks index
+        picks: List[Dict[int, np.ndarray]] = [{} for _ in range(B)]
+        built: List[Dict[int, Table]] = [{} for _ in range(B)]
+
+        def stage_sels(b: int) -> Dict[int, Table]:
+            """Row ``b``'s stage selections as Tables, built on first read
+            and kept: only the tuple and row-wise shapes and
+            :meth:`query_delta` read them."""
+            sels = built[b]
+            for si, idx in picks[b].items():
+                if si not in sels:
+                    sels[si] = tabs[si].take(idx)
+            return sels
+
+        sels_of = [lambda b=b: stage_sels(b) for b in range(B)]
         for si, st in enumerate(lp.stages):
-            if st.node_id in dropped:
-                continue
-            if (params_of(st.run_pred) | set(st.guards)) - available:
-                continue  # depends on a dropped stage: unusable
             stobj = self.exec_result.materialized.get(st.node_id)
-            if stobj is None:
+            if st.node_id in dropped or stobj is None:
                 continue
+            need = params_of(st.run_pred) | set(st.guards)
+            # rows lacking a param depend on a dropped stage: unusable there
+            live = [b for b in range(B) if not need - avail[b]]
             if not st.params_out:
                 # certification-only stage (opaque boundary): it binds no
                 # params, so its selection is never consumed — availability
                 # alone certifies the tables below it
-                used_stage_nodes.add(st.node_id)
+                for b in live:
+                    used[b].add(st.node_id)
                 continue
-            if any(_guard_dead(binding.get(g)) for g in st.guards):
-                sel = stobj.take(np.empty(0, dtype=np.int64))
-            else:
-                sel = self._stage_select(st, stobj, binding, param_stage,
-                                         stage_sel, param_col)
-            stage_sel[si] = sel
-            used_stage_nodes.add(st.node_id)
-            for p, colname in st.params_out.items():
-                if colname in sel.cols:
-                    binding[p] = _clean_binding_value(_uniq(sel.cols[colname]))
-                    param_stage[p] = si
-                    param_col[p] = colname
-                    available.add(p)
+            if not live:
+                continue
+            bs = [bindings[b] for b in live]
+            with trace.span("lineage.stage", node=st.node_id) as sp:
+                tab, route, scan = stobj, "plain", self._scan
+                if isinstance(stobj, StoredTable) and self.store is not None:
+                    # one binding of a plain conjunction scans the store in
+                    # situ and gathers only its params at the rows it picks;
+                    # other shapes, and several bindings (which lean on the
+                    # engine's identity-keyed sorted indexes), read the
+                    # cached decoded view
+                    if len(bs) == 1 and (_dead(bs[0], st.guards) or not any(
+                            _binding_groups(st.run_pred, bs[0], param_stage))):
+                        route = "in_situ"
+                        scan = lambda p, t, b, nid=st.node_id: self.store.scan(
+                            nid, p, b, self.scan_engine)
+                    else:
+                        tab, route = stobj.to_table(), "decoded"
+                idxs = _eval_pred(st.run_pred, tab, bs, param_stage, param_col,
+                                  [sels_of[b] for b in live], st.guards,
+                                  scan=scan, engine=self.scan_engine)
+                sp.set(route=route, rows=int(sum(map(len, idxs))))
+            tabs[si] = tab
+            outs = {p: c for p, c in st.params_out.items() if tab.has(c)}
+            for p, c in outs.items():
+                param_stage[p], param_col[p] = si, c
+            for b, idx in zip(live, idxs):
+                picks[b][si] = idx
+                used[b].add(st.node_id)
+                for p, c in outs.items():
+                    bindings[b][p] = _bind_value(
+                        tab.gather(c, idx) if route == "in_situ"
+                        else tab.cols[c][idx])
+                    avail[b].add(p)
 
-        lineage: Dict[str, np.ndarray] = {}
-        fallback: set = set()
+        lineages: List[Dict[str, np.ndarray]] = [{} for _ in range(B)]
+        fallback: List[set] = [set() for _ in range(B)]
         for sp in lp.source_preds:
-            if (params_of(sp.pred) | set(sp.guards)) - available:
-                fallback.add(sp.table)  # unbound params: superset path below
+            need = params_of(sp.pred) | set(sp.guards)
+            live = []
+            for b in range(B):
+                if need - avail[b]:
+                    fallback[b].add(sp.table)  # unbound params: superset path
+                else:
+                    live.append(b)
+            if not live:
                 continue
             t = self.catalog[sp.table]
             with trace.span("lineage.source", table=sp.table) as ts:
-                if sp.pred == FALSE or any(_guard_dead(binding.get(g)) for g in sp.guards):
-                    rids = np.array([], dtype=np.int64)
+                if sp.pred == FALSE:
+                    idxs = [_NO_ROWS] * len(live)
                 else:
-                    idx = _eval_pred(sp.pred, t, binding, param_stage,
-                                     stage_sel, param_col, scan=scan,
-                                     engine=self.scan_engine)
-                    rids = t.rids()[idx]
-                lineage[sp.table] = (
-                    np.union1d(lineage[sp.table], rids)
-                    if sp.table in lineage else np.unique(rids)
-                )
-                ts.set(rids=len(rids))
-        if fallback:
-            rr = self._superset_refine(t_o)
-            for tab in sorted(fallback):
-                rids = np.asarray(rr.lineage.get(tab, np.array([], dtype=np.int64)))
-                lineage[tab] = (
-                    np.union1d(lineage[tab], rids) if tab in lineage else rids
-                )
-            detail["iterations"] = rr.iterations
-        # a mandatory (opaque-UDF) stage that could not run — budget-dropped
-        # or missing from a reloaded store — leaves every table below it
-        # uncertified: the answer there is the well-defined whole-input
-        # superset, never an under-approximation
-        superset_set = set(fallback)
-        for nid, tabs in lp.superset_scope.items():
-            if nid not in used_stage_nodes:
-                superset_set.update(tabs)
-        if superset_set:
-            detail["superset_tables"] = sorted(superset_set)
-        ans = LineageAnswer(lineage, time.perf_counter() - t0, detail,
-                            precise={t: t not in superset_set for t in lineage})
-        if not superset_set and not fallback:
-            # precise, fully materialized answer: stash the final binding
-            # chain so a later append-only run can extend it in place
-            ans.delta_ctx = (binding, param_stage, param_col, stage_sel)
-        return ans
+                    idxs = _eval_pred(sp.pred, t, [bindings[b] for b in live],
+                                      param_stage, param_col,
+                                      [sels_of[b] for b in live], sp.guards,
+                                      scan=self._scan, engine=self.scan_engine)
+                n_rids = 0
+                for b, idx in zip(live, idxs):
+                    # the row indices are distinct, and so are a source
+                    # table's rids: a sort makes them a set
+                    rids = np.sort(t.rids()[idx])
+                    n_rids += len(rids)
+                    lin = lineages[b]
+                    lin[sp.table] = (np.union1d(lin[sp.table], rids)
+                                     if sp.table in lin else rids)
+                ts.set(rids=n_rids)
+
+        out = []
+        for b in range(B):
+            lin, detail = lineages[b], {}
+            if fallback[b]:
+                rr = self._superset_refine(rows[b])
+                for name in sorted(fallback[b]):
+                    rids = np.asarray(rr.lineage.get(name, _NO_ROWS))
+                    lin[name] = (np.union1d(lin[name], rids)
+                                 if name in lin else rids)
+                detail["iterations"] = rr.iterations
+            # a mandatory (opaque-UDF) stage that could not run — budget-
+            # dropped or missing from a reloaded store — leaves every table
+            # below it uncertified: the answer there is the well-defined
+            # whole-input superset, never an under-approximation
+            superset = set(fallback[b])
+            for nid, tabs_below in lp.superset_scope.items():
+                if nid not in used[b]:
+                    superset.update(tabs_below)
+            if superset:
+                detail["superset_tables"] = sorted(superset)
+            ans = LineageAnswer(lin, detail=detail,
+                                precise={t: t not in superset for t in lin})
+            if not superset:
+                # precise, fully materialized answer: keep the binding chain
+                # so a later append-only run can extend it in place
+                ans.delta_ctx = (bindings[b], param_stage, param_col,
+                                 sels_of[b])
+            out.append(ans)
+        dt = (time.perf_counter() - t0) / B
+        for ans in out:
+            ans.seconds = dt
+        return out
 
     # ------------------------------------------------------------------ #
     def query_delta(self, cached: LineageAnswer,
@@ -946,8 +1045,7 @@ class PredTrace:
         from .cost import prog_atoms
 
         t0 = time.perf_counter()
-        binding, param_stage, param_col, sel = ctx
-        stage_sel = sel() if callable(sel) else sel
+        binding, param_stage, param_col, stage_sels = ctx
         old = {m[:2]: m[2] for m in old_token[1]}
         lp = self.lineage_plan
         cm = self.scan_engine.cost_model
@@ -983,7 +1081,7 @@ class PredTrace:
             needed, st_pair = an["st", int(st.node_id)]
             if needed - set(binding):
                 return None
-            if any(_guard_dead(binding.get(g)) for g in st.guards):
+            if _dead(binding, st.guards):
                 continue  # selection is empty regardless of appended rows
             vkey = (table_uid(stobj), old_n, new_n)
             vcache = getattr(self, "_delta_views", None)
@@ -1000,8 +1098,8 @@ class PredTrace:
                                   for k, v in stobj.cols.items()},
                                  stobj.dicts, stobj.name)
                 vcache[vkey] = view
-            idx = _eval_pred(st.run_pred, view, binding, param_stage,
-                             stage_sel, param_col, analysis=st_pair)
+            (idx,) = _eval_pred(st.run_pred, view, [binding], param_stage,
+                                param_col, [stage_sels], analysis=st_pair)
             if len(idx):
                 return None  # stage_delta_match: binding would change
 
@@ -1024,8 +1122,7 @@ class PredTrace:
                            "warm_partitions": total_parts})
             if t.nrows == old_n:
                 continue  # untouched table: fully warm
-            if sp.pred == FALSE or any(
-                    _guard_dead(binding.get(g)) for g in sp.guards):
+            if sp.pred == FALSE or _dead(binding, sp.guards):
                 continue  # dead predicate matched nothing before or now
             # keyed by monotone table uid (never recycled), so an appended
             # table can never alias a stale cached view
@@ -1070,11 +1167,11 @@ class PredTrace:
             # planning, pruning and sorted indexes would cost more than the
             # scan itself, so the rescan route uses the tree evaluator and
             # whole-column tuple membership directly
-            idx = _eval_pred(sp.pred, scan_t, binding, param_stage,
-                             stage_sel, param_col,
-                             scan=self._scan if serial else None,
-                             analysis=sp_pair,
-                             engine=self.scan_engine if serial else None)
+            (idx,) = _eval_pred(sp.pred, scan_t, [binding], param_stage,
+                                param_col, [stage_sels],
+                                scan=self._scan if serial else None,
+                                analysis=sp_pair,
+                                engine=self.scan_engine if serial else None)
             rids = scan_t.rids()[idx]
             choice.done(time.perf_counter() - t1)
             if serial:
@@ -1102,7 +1199,7 @@ class PredTrace:
         }}
         ans = LineageAnswer(lineage, time.perf_counter() - t0, detail,
                             precise={t: True for t in lineage})
-        ans.delta_ctx = (binding, param_stage, param_col, stage_sel)
+        ans.delta_ctx = ctx
         return ans
 
     # ------------------------------------------------------------------ #
@@ -1231,256 +1328,25 @@ class PredTrace:
     def query_batch(
         self, rows: Sequence[Union[int, Dict[str, object]]]
     ) -> List[LineageAnswer]:
-        """Batched lineage querying: answer N output rows in ONE scan per
-        table.  Stage predicates and source predicates are evaluated for all
-        target rows together via :meth:`ScanEngine.scan_batch` (static atoms
-        once, equality thresholds vectorized); rows whose bindings need the
-        row-wise / tuple-membership treatment fall back to the per-row
-        evaluator, so answers are always identical to ``query(row)``."""
+        """Batched lineage querying: the stage walk (:meth:`_walk`) over all
+        ``rows`` at once, so each stage and source predicate is evaluated
+        for every row in one :func:`_eval_pred` call.
+
+        With several rows, plain-conjunction scans go through
+        :meth:`ScanEngine.scan_batch_idx` (one equality atom answered for
+        the whole batch from a cached sorted-column index, the other atoms
+        on the candidates) and stored stages through their cached decoded
+        view, which that index is keyed on; tuple groups probe the index
+        once per group for the batch.  The route follows the number of
+        rows, not the entry point: ``query_batch([row])`` walks as
+        ``query(row)`` does.  Answers are identical to ``query(row)``,
+        budget-degraded tables included, and carry ``detail["batch"]`` (the
+        batch size) and ``seconds`` as the walk's time over the rows."""
         with trace.span("lineage.query_batch", rows=len(rows)):
-            return self._query_batch(rows)
-
-    def _query_batch(
-        self, rows: Sequence[Union[int, Dict[str, object]]]
-    ) -> List[LineageAnswer]:
-        assert self.lineage_plan is not None and self.exec_result is not None
-        t0 = time.perf_counter()
-        B = len(rows)
-        if B == 0:
-            return []
-        if self.mat_plan is not None and self.mat_plan.dropped:
-            # budget-degraded plans mix precise and iterative answers per
-            # table; answer row-by-row (query() owns that logic)
-            return [self.query(r) for r in rows]
-        with trace.span("lineage.bind"):
-            bindings = [self._output_binding(r) for r in rows]
-        scan = self._scan
-
-        param_stage: Dict[str, int] = {}
-        param_col: Dict[str, str] = {}
-        stage_tables: Dict[int, Table] = {}
-        stage_idxs: List[Dict[int, np.ndarray]] = [{} for _ in range(B)]
-        empty = np.array([], dtype=np.int64)
-
-        sel_tables: List[Dict[int, Table]] = [{} for _ in range(B)]
-
-        def stage_sels(b: int) -> Dict[int, Table]:
-            """Materialized stage selections for one target row — built (and
-            cached) only for the bindings that need the row-wise/tuple
-            evaluator; a stage's selection never changes once computed."""
-            cache = sel_tables[b]
-            for si, idx in stage_idxs[b].items():
-                if si not in cache:
-                    cache[si] = stage_tables[si].take(idx)
-            return cache
-
-        def sel_col(b: int, sid: int, p: str) -> np.ndarray:
-            """A stage-selection column for one target row, without
-            materializing the selection Table."""
-            return stage_tables[sid].cols[param_col[p]][stage_idxs[b][sid]]
-
-        def tuple_batch(pred, table, entries) -> Optional[Dict[int, np.ndarray]]:
-            """Batched tuple-group evaluation for rows sharing one group
-            signature — mirrors ``_eval_pred``'s zip-semantics path, but the
-            leading membership of every group runs against the engine's
-            sorted index instead of a full-table ``isin`` per row.  Returns
-            None when the shape isn't batchable (atom-less group)."""
-            bs = [b for b, _ in entries]
-            tg = entries[0][1]
-            conj = conjuncts(pred)
-            consumed: List[Expr] = []
-            groups: List[Tuple[int, List[Tuple[Expr, str]]]] = []
-            for sid, plist in tg.items():
-                atoms: List[Tuple[Expr, str]] = []
-                for a in conj:
-                    ap = params_of(a)
-                    if len(ap) == 1 and next(iter(ap)) in plist and isinstance(a, BinOp):
-                        p = next(iter(ap))
-                        lhs = a.left if isinstance(a.right, Param) else a.right
-                        atoms.append((lhs, p))
-                        consumed.append(a)
-                if not atoms:
-                    return None  # membership-only group: leave to _eval_pred
-                groups.append((sid, atoms))
-            rest = [a for a in conj if a not in consumed]
-            rest_pred = None
-            if rest:
-                from .expr import land
-
-                rest_pred = land(*rest)
-
-            def lhs_vals(lhs, idx):
-                if isinstance(lhs, Col):
-                    return table.cols[lhs.name][idx]
-                env = {c: table.cols[c][idx] for c in cols_of(lhs)}
-                return np.asarray(eval_np(lhs, env, {}, n=len(idx)))
-
-            out: Dict[int, np.ndarray] = {}
-            for sid, atoms in groups:
-                lhs0, p0 = atoms[0]
-                cand0 = self.scan_engine.member_batch_idx(
-                    table, lhs0, [sel_col(b, sid, p0) for b in bs]
-                )
-                for j, b in enumerate(bs):
-                    idx = _tuple_narrow(
-                        table, cand0[j],
-                        [(lhs, sel_col(b, sid, p)) for lhs, p in atoms],
-                        [lhs_vals(lhs0, cand0[j])])
-                    out[b] = idx if b not in out else np.intersect1d(out[b], idx)
-                self.scan_engine.stats.bump(tuple_index_groups=len(bs))
-            if rest_pred is not None:
-                for b in bs:
-                    out[b] = _holds_on(rest_pred, table, out[b], bindings[b])
-            return out
-
-        def batch_indices(pred, table, guards) -> List[Optional[np.ndarray]]:
-            """Matching row indices per target row; None marks guard-dead rows."""
-            dead = [
-                any(_guard_dead(bindings[b].get(g)) for g in guards)
-                for b in range(B)
-            ]
-            analysis = (params_of(pred), _eq_only_params(pred))
-            simple: List[int] = []
-            per_row: List[int] = []
-            tuple_groups: Dict[Tuple, List[Tuple[int, Dict]]] = {}
-            idxs: List[Optional[np.ndarray]] = [None] * B
-            for b in range(B):
-                if dead[b]:
-                    continue
-                tg, rw = _binding_groups(pred, bindings[b], param_stage, analysis)
-                if rw:  # row-wise binding: exact per-row evaluation
-                    per_row.append(b)
-                elif tg:  # tuple groups: batchable by group signature
-                    sig = tuple(sorted(
-                        (sid, tuple(sorted(plist))) for sid, plist in tg.items()
-                    ))
-                    tuple_groups.setdefault(sig, []).append((b, tg))
-                else:
-                    simple.append(b)
-            for entries in tuple_groups.values():
-                with trace.span("lineage.tuple", rows=sum(
-                        len(stage_idxs[b].get(sid, empty))
-                        for b, tg in entries for sid in tg)) as ts:
-                    res = tuple_batch(pred, table, entries)
-                    if res is not None:
-                        ts.set(route="index")
-                if res is None:
-                    per_row.extend(b for b, _ in entries)
-                else:
-                    for b, idx in res.items():
-                        idxs[b] = idx
-            for b in per_row:
-                idxs[b] = _eval_pred(pred, table, bindings[b], param_stage,
-                                     stage_sels(b), param_col, scan=scan,
-                                     engine=self.scan_engine)
-            if simple:
-                batched = self.scan_engine.scan_batch_idx(
-                    pred, table, [bindings[b] for b in simple]
-                )
-                for b, idx in zip(simple, batched):
-                    idxs[b] = idx
-            return idxs
-
-        for si, st in enumerate(self.lineage_plan.stages):
-            if not st.params_out:
-                continue  # certification-only stage: binds nothing
-            table = self.exec_result.materialized[st.node_id]
-            stored = isinstance(table, StoredTable)
-            with trace.span("lineage.stage", node=st.node_id,
-                            route="decoded" if stored else "plain") as ss:
-                if stored:
-                    # the batch path leans on the engine's identity-keyed
-                    # sorted indexes; read the store through its cached
-                    # decoded view
-                    table = table.to_table()
-                stage_tables[si] = table
-                idxs = batch_indices(st.run_pred, table, st.guards)
-                lens = np.fromiter(
-                    (0 if idx is None else len(idx) for idx in idxs), np.int64, B
-                )
-                ss.set(rows=int(lens.sum()))
-                offs = np.zeros(B, dtype=np.int64)
-                np.cumsum(lens[:-1], out=offs[1:])
-                flat = (
-                    np.concatenate([idx for idx in idxs if idx is not None and len(idx)])
-                    if lens.sum() else empty
-                )
-                for b in range(B):
-                    stage_idxs[b][si] = empty if idxs[b] is None else idxs[b]
-                for p, colname in st.params_out.items():
-                    if colname not in table.cols:
-                        continue
-                    param_stage[p] = si
-                    param_col[p] = colname
-                    col = table.cols[colname]
-                    colf = col[flat]
-                    nonempty = np.nonzero(lens)[0]
-                    if len(nonempty):
-                        # segment min == max detects the common constant-column
-                        # case without a per-row unique.  reduceat runs over the
-                        # non-empty segments' offsets only: they are strictly
-                        # increasing and in range, and consecutive non-empty
-                        # offsets are exact segment boundaries (empty segments
-                        # contribute no elements), so no clipping is needed —
-                        # clipping would shift the last segment's boundary.
-                        mins = np.minimum.reduceat(colf, offs[nonempty])
-                        maxs = np.maximum.reduceat(colf, offs[nonempty])
-                        seg = np.full(B, -1, dtype=np.int64)
-                        seg[nonempty] = np.arange(len(nonempty))
-                    fkind = col.dtype.kind == "f"
-                    ikind = col.dtype.kind in "iu"
-                    for b in range(B):
-                        ln = lens[b]
-                        if ln == 0:
-                            bindings[b][p] = col[:0]
-                        elif ln == 1 or mins[seg[b]] == maxs[seg[b]]:  # constant
-                            v = colf[offs[b]]
-                            if (fkind and np.isnan(v)) or (ikind and v == -1):
-                                bindings[b][p] = col[:0]  # null sentinel: dead
-                            else:
-                                bindings[b][p] = v.item()
-                        else:
-                            bindings[b][p] = _clean_binding_value(
-                                np.unique(colf[offs[b]:offs[b] + ln])
-                            )
-
-        lineages: List[Dict[str, np.ndarray]] = [{} for _ in range(B)]
-        for sp in self.lineage_plan.source_preds:
-            t = self.catalog[sp.table]
-            with trace.span("lineage.source", table=sp.table) as ts:
-                if sp.pred == FALSE:
-                    idxs = [None] * B
-                else:
-                    idxs = batch_indices(sp.pred, t, sp.guards)
-                n_rids = 0
-                for b in range(B):
-                    idx = idxs[b]
-                    rids = empty if idx is None else t.rids()[idx]
-                    n_rids += len(rids)
-                    lin = lineages[b]
-                    if sp.table in lin:
-                        lin[sp.table] = np.union1d(lin[sp.table], rids)
-                    else:
-                        # candidate indices are distinct by construction; rids of
-                        # a source table are unique per row — sort suffices
-                        rids.sort()
-                        lin[sp.table] = rids
-                ts.set(rids=n_rids)
-        dt = time.perf_counter() - t0
-        out = []
-        for b in range(B):
-            # the batch path only runs with every stage materialized
-            # (degraded plans fall back to per-row query() above), so every
-            # answer is certified precise
-            ans = LineageAnswer(lineages[b], dt / B,
-                                precise={t: True for t in lineages[b]})
-            ans.detail["batch"] = B
-            # stage selections build lazily: query_delta only consults them
-            # for the tuple/row-wise binding shapes
-            ans.delta_ctx = (bindings[b], param_stage, param_col,
-                             (lambda b=b: stage_sels(b)))
-            out.append(ans)
-        return out
+            answers = self._walk(rows)
+        for ans in answers:
+            ans.detail["batch"] = len(answers)
+        return answers
 
     # ------------------------------------------------------------------ #
     def query_iterative(

@@ -15,7 +15,7 @@ from repro.core.expr import (
     BinOp, Col, IsIn, Lit, Param, conjuncts, eval_np, land, params_of,
 )
 from repro.core.lineage import _binding_groups, _eval_pred, _tuple_members
-from repro.core.plan import Stage
+from repro.core.plan import LineagePlan, Stage
 from repro.core.scan import ScanEngine
 from repro.core.store import encode_table
 from repro.core.table import (
@@ -117,7 +117,7 @@ def _whole_table_mask(pred, table, binding, param_stage, stage_sel,
                 lhs = a.left if isinstance(a.right, Param) else a.right
                 atoms.append((lhs, np.asarray(stage_sel[sid].cols[param_col[p]])))
                 consumed.append(a)
-        _, idx = _tuple_members(table, atoms, engine)
+        _, (idx,) = _tuple_members(table, [atoms], engine)
         m = np.zeros(table.nrows, dtype=bool)
         m[idx] = True
         mask &= m
@@ -129,6 +129,14 @@ def _whole_table_mask(pred, table, binding, param_stage, stage_sel,
         else:
             mask &= engine.scan(land(*rest), table, binding)
     return mask
+
+
+def _eval_one(pred, table, binding, param_stage, stage_sel, param_col, **kw):
+    """The lineage walk's evaluator over one binding whose stage selections
+    are ``stage_sel``."""
+    (idx,) = _eval_pred(pred, table, [binding], param_stage, param_col,
+                        [lambda: stage_sel], **kw)
+    return idx
 
 
 def _assert_row_ids(idx):
@@ -149,8 +157,8 @@ def test_index_path_answers_as_whole_column_path(kind):
     args = (pred, table, binding, {"p": 0, "q": 0}, {0: stage},
             {"p": "x", "q": "y"})
     engine = ScanEngine()
-    old = _eval_pred(*args)
-    new = _eval_pred(*args, engine=engine)
+    old = _eval_one(*args)
+    new = _eval_one(*args, engine=engine)
     _assert_row_ids(new)
     np.testing.assert_array_equal(new, old)
     want = _brute(table, stage, lhs, rest, binding)
@@ -179,10 +187,10 @@ def test_rowwise_branch_counts_a_whole_table_rest():
     binding = {"p": stage.cols["x"], "q": stage.cols["y"],
                "lo": win.cols["lo"], "hi": win.cols["hi"]}
     engine = ScanEngine()
-    got = _eval_pred(pred, table, binding,
-                     {"p": 0, "q": 0, "lo": 1, "hi": 1}, {0: stage, 1: win},
-                     {"p": "x", "q": "y", "lo": "lo", "hi": "hi"},
-                     scan=engine.scan, engine=engine)
+    got = _eval_one(pred, table, binding,
+                    {"p": 0, "q": 0, "lo": 1, "hi": 1}, {0: stage, 1: win},
+                    {"p": "x", "q": "y", "lo": "lo", "hi": "hi"},
+                    scan=engine.scan, engine=engine)
     _assert_row_ids(got)
     member = _brute(table, stage, lhs, [Col("b") >= Lit(0)], {})
     bv = table.cols["b"]
@@ -200,8 +208,8 @@ def test_membership_only_group_scans_the_whole_table():
     pred = land(IsIn(Col("a"), Param("p")), IsIn(Col("b"), Param("q")))
     binding = {"p": stage.cols["x"], "q": stage.cols["y"]}
     engine = ScanEngine()
-    got = _eval_pred(pred, table, binding, {"p": 0, "q": 0}, {0: stage},
-                     {"p": "x", "q": "y"}, scan=engine.scan, engine=engine)
+    got = _eval_one(pred, table, binding, {"p": 0, "q": 0}, {0: stage},
+                    {"p": "x", "q": "y"}, scan=engine.scan, engine=engine)
     _assert_row_ids(got)
     want = (np.isin(table.cols["a"], stage.cols["x"])
             & np.isin(table.cols["b"], stage.cols["y"]))
@@ -212,15 +220,38 @@ def test_membership_only_group_scans_the_whole_table():
     assert stats["scans"] == 1
 
 
+def _hand_walk(table, pred, stored):
+    """A PredTrace whose lineage plan is written by hand: output param ``o``
+    selects every row of two small stages, which bind the set ``r`` and the
+    stage pair ``p``, ``q`` of ``_tables``; a third stage, ``table`` plain or
+    encoded, then runs ``pred`` with those params."""
+    _, stage, _, _, _, extra = _tables("rest_set_param")
+    key = lambda n: np.zeros(n, np.int32)
+    s_r = Table({"g": key(3), "rv": extra["r"]}, name="r")
+    s_pq = Table({"g": key(stage.nrows), "x": stage.cols["x"],
+                  "y": stage.cols["y"]}, name="pq")
+    pt = PredTrace({"src": table}, O.Source("src"), store=stored or None)
+    pt.run()
+    pt.lineage_plan = LineagePlan(pt.plan, {"o": "g"}, [
+        Stage(1, Col("g").eq(Param("o")), {"r": "rv"}),
+        Stage(2, Col("g").eq(Param("o")), {"p": "x", "q": "y"}),
+        Stage(7, pred, {"z": "a"}),
+    ], [])
+    pt.exec_result.materialized = {
+        1: s_r, 2: s_pq, 7: encode_table(table) if stored else table}
+    pt.mat_plan = None
+    return pt
+
+
 @pytest.mark.parametrize("stored", [False, True], ids=["plain", "stored"])
 def test_stage_select_returns_the_rows_of_the_whole_table_mask(stored):
     table, stage, lhs, _, rest, extra = _tables("rest_set_param")
     pred = land(lhs.eq(Param("p")), Col("b").eq(Param("q")), *rest)
     binding = {"p": stage.cols["x"], "q": stage.cols["y"], **extra}
     walk = ({"p": 0, "q": 0}, {0: stage}, {"p": "x", "q": "y"})
-    pt = PredTrace({"src": table}, O.Source("src"), store=stored or None)
-    stobj = encode_table(table) if stored else table
-    sel = pt._stage_select(Stage(7, pred, {}), stobj, binding, *walk)
+    pt = _hand_walk(table, pred, stored)
+    ans = pt.query({"g": 0})
+    sel = ans.delta_ctx[3]()[2]  # the selection of the walk's third stage
     want = table.mask(_whole_table_mask(pred, table, binding, *walk))
     assert sel.nrows == want.nrows > 0
     for c in want.cols:
@@ -337,13 +368,14 @@ def test_rest_atoms_launch_nothing_over_lineitem(tpch_db, qname):
     assert not [s for s in spans if s.name == "scan"
                 and s.parent in with_tuple]
 
-    binding, param_stage, param_col, stage_sel = ans.delta_ctx
+    binding, param_stage, param_col, stage_sels = ans.delta_ctx
+    stage_sel = stage_sels()
     preds = _tuple_source_preds(pt, binding, param_stage, "lineitem")
     assert preds, f"{qname}: no tuple group on lineitem"
     for pred in preds:
         walk = (pred, li, binding, param_stage, stage_sel, param_col)
         before = eng.stats()
-        idx = _eval_pred(*walk, scan=eng.scan, engine=eng)
+        idx = _eval_one(*walk, scan=eng.scan, engine=eng)
         mid = eng.stats()
         old = _whole_table_mask(*walk, engine=eng)
         after = eng.stats()
@@ -369,7 +401,7 @@ def test_delta_view_rows_match_the_grown_table(with_engine):
     binding = {"p": stage.cols["x"], "q": stage.cols["y"], **extra}
     walk = ({"p": 0, "q": 0}, {0: stage}, {"p": "x", "q": "y"})
     engine = ScanEngine() if with_engine else None
-    idx = _eval_pred(pred, view, binding, *walk, engine=engine)
+    idx = _eval_one(pred, view, binding, *walk, engine=engine)
     _assert_row_ids(idx)
     full = np.flatnonzero(_whole_table_mask(pred, grown, binding, *walk))
     assert len(full[full >= off]) > 0

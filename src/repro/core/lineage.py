@@ -24,8 +24,8 @@ from . import ops as O
 from . import trace
 from .executor import ExecResult, Executor
 from .expr import (
-    BinOp, Col, Expr, FALSE, IsIn, Param, cols_of, conjuncts, eval_np, land,
-    params_of,
+    BinOp, Col, Expr, FALSE, IsIn, Param, cols_of, conjuncts, eval_np, key,
+    land, params_of,
 )
 from .iterative import IterativeInference, IterativePlan, RefineResult, refine
 from .plan import (
@@ -108,30 +108,63 @@ def _zone_restrict(table: Table, atoms) -> np.ndarray:
     return np.arange(n)
 
 
+def _fold_atoms(atoms):
+    """``(atoms, folded)``: the tuple group ``atoms`` (per binding, its
+    ``(lhs, stage values)`` pairs) with each set of atoms sharing a left
+    side folded into its first atom, and the number of atoms folded away
+    per binding.  A folded atom keeps the stage rows where the set's values
+    agree (``==``: NaN never agrees, as ``isin`` never matches it), which is
+    exact: L(r)=a_i and L(r)=b_i for one stage row i holds iff a_i=b_i and
+    L(r)=a_i.  A join key's pushed equality pair so becomes one atom."""
+    first: Dict[object, int] = {}
+    dups = []  # (kept atom, atom folded into it)
+    for j, (lhs, _) in enumerate(atoms[0]):
+        i = first.setdefault(key(lhs), j)
+        if i != j:
+            dups.append((i, j))
+    if not dups:
+        return atoms, 0
+    keep = sorted(set(first.values()))
+    out = []
+    for a in atoms:
+        agree = np.ones(len(a[0][1]), dtype=bool)
+        for i, j in dups:
+            agree &= a[i][1] == a[j][1]
+        out.append([(a[i][0], a[i][1][agree]) for i in keep])
+    return out, len(dups)
+
+
 def _tuple_members(table: Table, atoms, engine: Optional[ScanEngine] = None):
-    """``(route, idxs)``: per binding, the sorted row indices of ``table``
-    whose left-side values form a tuple of that binding's stage selection.
-    ``atoms[b]`` holds binding ``b``'s ``(lhs, stage values)`` pairs, one
-    per tuple column; every binding has the same left sides.
+    """``(route, folded, idxs)``: per binding, the sorted row indices of
+    ``table`` whose left-side values form a tuple of that binding's stage
+    selection, and the atoms per binding that shared a left side and were
+    folded away first (:func:`_fold_atoms`).  ``atoms[b]`` holds binding
+    ``b``'s ``(lhs, stage values)`` pairs, one per tuple column; every
+    binding has the same left sides.
 
     With an ``engine`` and a plain-column leading atom, the leading atom's
     candidates of every binding come from one probe of the engine's cached
-    sorted-column index (route ``index``); otherwise from a whole-column
-    ``isin`` after zone pruning (route ``isin``), which suits tables seen
-    once, such as fresh delta views."""
+    sorted-column index (route ``index``); a group folded to one atom is
+    answered by the probe alone.  Otherwise the candidates come from a
+    whole-column ``isin`` after zone pruning (route ``isin``), which suits
+    tables seen once, such as fresh delta views."""
+    n_atoms = len(atoms[0])
+    atoms, folded = _fold_atoms(atoms)
     lhs0 = atoms[0][0][0]
     if engine is not None and isinstance(lhs0, Col):
         route, col = "index", table.cols[lhs0.name]
         cands = engine.member_batch_idx(table, lhs0, [a[0][1] for a in atoms])
-        idxs = [_tuple_narrow(table, c, a, [col[c]])
-                for c, a in zip(cands, atoms)]
+        idxs = cands if len(atoms[0]) == 1 else [
+            _tuple_narrow(table, c, a, [col[c]]) for c, a in zip(cands, atoms)]
     else:
         route = "isin"
         idxs = [_tuple_narrow(table, _zone_restrict(table, a), a, [])
                 for a in atoms]
     if engine is not None:
-        engine.stats.bump(**{f"tuple_{route}_groups": len(atoms)})
-    return route, idxs
+        engine.stats.bump(**{f"tuple_{route}_groups": len(atoms),
+                             "tuple_atoms": n_atoms * len(atoms),
+                             "tuple_folded": folded * len(atoms)})
+    return route, folded, idxs
 
 
 def _tuple_narrow(table: Table, idx: np.ndarray, atoms, vals) -> np.ndarray:
@@ -270,12 +303,13 @@ def _eval_shape(pred: Expr, table: Table, bindings, tuple_groups, rowwise,
         sels = [s() for s in stage_sels]
         with trace.span("lineage.tuple", rows=sum(
                 sel[sid].nrows for sel in sels for sid, _ in groups)) as ts:
-            routes = []
+            routes, folded = [], 0
             for sid, atoms in groups:
-                route, idxs = _tuple_members(table, [
+                route, f, idxs = _tuple_members(table, [
                     [(lhs, np.asarray(sel[sid].cols[param_col[p]]))
                      for _, lhs, p in atoms] for sel in sels], engine)
                 routes.append(route)
+                folded += f
                 cands = idxs if cands is None else [
                     np.intersect1d(c, i, assume_unique=True)
                     for c, i in zip(cands, idxs)]
@@ -287,7 +321,7 @@ def _eval_shape(pred: Expr, table: Table, bindings, tuple_groups, rowwise,
                 rest_rows = sum(map(len, cands))
                 cands = [_holds_on(rest_pred, table, c, b)
                          for c, b in zip(cands, bindings)]
-            ts.set(route="+".join(routes),
+            ts.set(route="+".join(routes), folded=folded,
                    rest="cand" if on_cand else "table", rest_rows=rest_rows)
     if on_cand:
         return cands

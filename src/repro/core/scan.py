@@ -2219,6 +2219,11 @@ class ScanStats:
     # table (row-wise bindings, or a group that gave no candidates)
     tuple_rest_cand: int = 0
     tuple_rest_table: int = 0
+    # atoms of the tuple groups probed (once per binding), and of those the
+    # atoms folded into another of the group with the same left side (a
+    # join key's pushed equality pair), so never narrowed or code-checked
+    tuple_atoms: int = 0
+    tuple_folded: int = 0
     # the engine's bounded caches, registered for the stats() snapshot
     caches: Dict[str, "LRUCache"] = field(default_factory=dict, repr=False)
     # counter increments are read-modify-write; concurrent scans (the

@@ -14,8 +14,11 @@ from repro.core.eager import oracle_lineage_for_values
 from repro.core.expr import (
     BinOp, Col, IsIn, Lit, Param, conjuncts, eval_np, land, params_of,
 )
-from repro.core.lineage import _binding_groups, _eval_pred, _tuple_members
-from repro.core.plan import LineagePlan, Stage
+from repro.core import executor
+from repro.core.lineage import (
+    _binding_groups, _eval_pred, _holds_on, _tuple_members, _tuple_narrow,
+)
+from repro.core.plan import LineagePlan, SourcePred, Stage
 from repro.core.scan import ScanEngine
 from repro.core.store import encode_table
 from repro.core.table import (
@@ -117,7 +120,7 @@ def _whole_table_mask(pred, table, binding, param_stage, stage_sel,
                 lhs = a.left if isinstance(a.right, Param) else a.right
                 atoms.append((lhs, np.asarray(stage_sel[sid].cols[param_col[p]])))
                 consumed.append(a)
-        _, (idx,) = _tuple_members(table, [atoms], engine)
+        _, _, (idx,) = _tuple_members(table, [atoms], engine)
         m = np.zeros(table.nrows, dtype=bool)
         m[idx] = True
         mask &= m
@@ -258,6 +261,152 @@ def test_stage_select_returns_the_rows_of_the_whole_table_mask(stored):
         np.testing.assert_array_equal(np.asarray(sel.cols[c]),
                                       np.asarray(want.cols[c]))
     assert pt.scan_engine.stats()["tuple_rest_cand"] == 1
+
+
+def _fold_case(case):
+    """(table, stage, tuple atoms as (table column, stage column), atoms
+    folded away per binding) for one fold case.  The stage's ``g`` splits
+    it into two output rows' selections of six rows each."""
+    rng = np.random.default_rng(11)
+    n = 4000
+    a = rng.integers(0, 250, n).astype(np.int32)
+    b = rng.integers(0, 20, n).astype(np.int32)
+    src = {"a": a, "b": b}
+    pick = rng.choice(n, 12, replace=False)
+    x = a[pick]
+    st = {"g": np.repeat(np.int32([0, 1]), 6), "x": x, "y": x.copy()}
+    atoms, folded = [("a", "x"), ("a", "y")], 1
+    if case == "cols_differ":  # the fold drops stage rows 1, 4 and 8
+        st["y"][[1, 4, 8]] = a[pick[[2, 5, 9]]]
+    elif case == "three_way":
+        st["z"] = x.copy()
+        st["z"][[0, 7]] += 1
+        atoms, folded = atoms + [("a", "z")], 2
+    elif case in ("two_col_one_dup", "nan_stage"):
+        st["w"] = b[pick]
+        atoms = atoms + [("b", "w")]
+    if case == "nan_stage":
+        f = a / 4.0
+        f[::9] = np.nan
+        src["a"] = f
+        st["x"] = f[pick]
+        st["x"][[0, 6]] = np.nan
+        st["y"] = st["x"].copy()
+        st["y"][[0, 1]] = [np.nan, 7.5]
+    return (Table.from_dict(src, name="src"), Table(st, name="st"), atoms,
+            folded)
+
+
+def _fold_walk(table, stage, pred, pcol):
+    """A PredTrace whose lineage plan is written by hand: output param ``o``
+    selects the stage rows of one ``g``, which bind the params ``pcol``
+    that the source predicate ``pred`` over ``table`` reads."""
+    pt = PredTrace({"src": table}, O.Source("src"))
+    pt.run()
+    pt.lineage_plan = LineagePlan(pt.plan, {"o": "g"}, [
+        Stage(1, Col("g").eq(Param("o")), pcol)],
+        [SourcePred(0, "src", pred)])
+    pt.exec_result.materialized = {1: stage}
+    pt.mat_plan = None
+    return pt
+
+
+def _counting_composite_codes(monkeypatch):
+    calls = []
+    real = executor.composite_codes
+
+    def counted(*args):
+        calls.append(1)
+        return real(*args)
+
+    monkeypatch.setattr(executor, "composite_codes", counted)
+    return calls
+
+
+@pytest.mark.parametrize("entry", ["query", "query_batch"])
+@pytest.mark.parametrize("route", ["index", "isin"])
+@pytest.mark.parametrize("case", [
+    "join_pair", "cols_differ", "three_way", "two_col_one_dup", "nan_stage",
+])
+def test_folded_group_answers_as_unfolded(case, route, entry, monkeypatch):
+    """Atoms of a tuple group that share a left side fold into one before
+    the probe: both entry points, and the engine-less evaluator of
+    ``query_delta``, give the row ids of the unfolded narrowing and of a
+    row-by-row check, on the index route (plain column) and the ``isin``
+    route (an expression left side)."""
+    table, stage, cols, folded = _fold_case(case)
+    lhs = {c: Col(c) if route == "index" else Col(c) + Lit(0)
+           for c, _ in cols}
+    pcol = {f"p{j}": sc for j, (_, sc) in enumerate(cols)}
+    rest = Col("b") >= Lit(1)
+    pred = land(*(lhs[c].eq(Param(f"p{j}")) for j, (c, _) in enumerate(cols)),
+                rest)
+    pt = _fold_walk(table, stage, pred, pcol)
+    rows = [{"g": 0}, {"g": 1}]
+    calls = _counting_composite_codes(monkeypatch)
+    s0 = pt.scan_engine.stats()
+    trace.start()
+    try:
+        answers = (pt.query_batch(rows) if entry == "query_batch"
+                   else [pt.query(r) for r in rows])
+    finally:
+        spans = trace.stop()
+    s1 = pt.scan_engine.stats()
+    # groups folded to one atom are answered with no composite check
+    assert bool(calls) == (len(cols) - folded > 1)
+    tuples = [s for s in spans if s.name == "lineage.tuple"]
+    assert len(tuples) == (1 if entry == "query_batch" else 2)
+    assert all(s.attrs["folded"] == folded for s in tuples)
+    assert s1["tuple_atoms"] - s0["tuple_atoms"] == 2 * len(cols)
+    assert s1["tuple_folded"] - s0["tuple_folded"] == 2 * folded
+    assert s1[f"tuple_{route}_groups"] - s0[f"tuple_{route}_groups"] == 2
+
+    sels = [stage.mask(stage.cols["g"] == g) for g in (0, 1)]
+    for ans, sel in zip(answers, sels):
+        got = ans.lineage["src"]
+        _assert_row_ids(got)
+        unfolded = _holds_on(rest, table, _tuple_narrow(
+            table, np.arange(table.nrows),
+            [(lhs[c], np.asarray(sel.cols[sc])) for c, sc in cols], []), {})
+        want = set(zip(*(sel.cols[sc].tolist() for _, sc in cols)))
+        brute = [r for r in range(table.nrows)
+                 if table.cols["b"][r] >= 1 and all(
+                     table.cols[c][r] == table.cols[c][r] for c, _ in cols)
+                 and tuple(table.cols[c][r].item() for c, _ in cols) in want]
+        np.testing.assert_array_equal(got, unfolded)
+        np.testing.assert_array_equal(got, brute)
+        binding, param_stage, param_col, stage_sels = ans.delta_ctx
+        (delta,) = _eval_pred(pred, table, [binding], param_stage, param_col,
+                              [stage_sels])
+        np.testing.assert_array_equal(got, delta)
+    assert all(len(a.lineage["src"]) for a in answers)
+    if case == "cols_differ":  # the dropped stage rows' keys leave the answer
+        x_only = np.flatnonzero(np.isin(table.cols["a"], sels[0].cols["x"])
+                                & (table.cols["b"] >= 1))
+        assert len(answers[0].lineage["src"]) < len(x_only)
+
+
+def test_q14_join_pairs_fold_to_the_index_probe(walk_pts, oracle_sets,
+                                                monkeypatch):
+    """q14's two source predicates each carry its join key's pushed
+    equality pair (``l_partkey`` against both stage key columns, and
+    ``p_partkey`` likewise): each folds to one atom, so the index probe
+    answers the group and no composite code is built."""
+    pt = walk_pts("numpy", "q14")
+    calls = _counting_composite_codes(monkeypatch)
+    s0 = pt.scan_engine.stats()
+    trace.start()
+    try:
+        ans = pt.query(0)
+    finally:
+        spans = trace.stop()
+    s1 = pt.scan_engine.stats()
+    assert not calls
+    tuples = [s for s in spans if s.name == "lineage.tuple"]
+    assert len(tuples) == 2 and all(s.attrs["folded"] == 1 for s in tuples)
+    assert s1["tuple_atoms"] - s0["tuple_atoms"] == 4
+    assert s1["tuple_folded"] - s0["tuple_folded"] == 2
+    assert lineage_sets(ans.lineage) == oracle_sets(pt, "q14", 0)
 
 
 def _engine(backend):

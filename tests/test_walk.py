@@ -105,7 +105,7 @@ def _batch_rest_counts(db):
     counters as the same rows' one-row queries do."""
     pt = _prepared(db, "q10")
     names = ("tuple_rest_cand", "tuple_rest_table", "tuple_index_groups",
-             "tuple_isin_groups")
+             "tuple_isin_groups", "tuple_atoms", "tuple_folded")
     rows = [0, 1, 2]
     s0 = pt.scan_engine.stats()
     for r in rows:
@@ -117,6 +117,7 @@ def _batch_rest_counts(db):
     bat = {n: s2[n] - s1[n] for n in names}
     assert bat == one
     assert bat["tuple_rest_cand"] >= len(rows) and bat["tuple_rest_table"] == 0
+    assert 0 < bat["tuple_folded"] < bat["tuple_atoms"]
 
 
 CASES = {
